@@ -27,6 +27,7 @@ import jax
 import numpy as np
 
 from ..checkpoint import restore_checkpoint
+from ..launch.mesh import make_mesh
 from .sharding import ShardingConfig, build_param_specs
 
 Tree = Any
@@ -96,7 +97,7 @@ def build_mesh(plan: RemeshPlan):
             continue
         shape.append(s)
         names.append(a)
-    return jax.make_mesh(tuple(shape), tuple(names))
+    return make_mesh(tuple(shape), tuple(names))
 
 
 def reshard_restore(

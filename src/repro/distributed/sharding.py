@@ -121,15 +121,20 @@ def ambient_dp_axes() -> Optional[Tuple[str, ...]]:
     mesh context (CPU smoke tests) this returns None and no constraint is
     applied.
     """
-    try:
-        from jax.interpreters import pxla  # noqa: PLC0415
-
-        mesh = pxla.thread_resources.env.physical_mesh
-        if mesh.empty:
-            return None
-        return tuple(a for a in mesh.axis_names if a in ("pod", "data")) or None
-    except Exception:  # noqa: BLE001
+    mesh = _ambient_mesh()
+    if mesh is None:
         return None
+    return tuple(a for a in mesh.axis_names if a in ("pod", "data")) or None
+
+
+def _ambient_mesh():
+    """The mesh set by ``jax.set_mesh``, or None when there is none or when
+    tracing inside a manual (``shard_map``) region, where layout
+    constraints on the global view do not apply."""
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty or mesh.manual_axes:
+        return None
+    return mesh
 
 
 def constrain_batch_dim(x, batch_dim: int = 1):
@@ -148,10 +153,7 @@ def constrain_batch_dim(x, batch_dim: int = 1):
 
 
 def _grid(dp: Tuple[str, ...]) -> int:
-    from jax.interpreters import pxla  # noqa: PLC0415
-
-    mesh = pxla.thread_resources.env.physical_mesh
-    sizes = dict(zip(mesh.axis_names, mesh.devices.shape))
+    sizes = _ambient_mesh().shape
     out = 1
     for a in dp:
         out *= sizes[a]
@@ -188,16 +190,12 @@ def constrain_kv_for_cache(k, n_kv_heads: int, seq_dim: int = 1):
     reshard 2·L times per prefill — tens of seconds of all-gather at 32k
     (§Perf H2). Constraining here makes the write layout-aligned.
     """
-    from jax.interpreters import pxla  # noqa: PLC0415
     from jax.sharding import PartitionSpec  # noqa: PLC0415
 
-    try:
-        mesh = pxla.thread_resources.env.physical_mesh
-        if mesh.empty:
-            return k
-    except Exception:  # noqa: BLE001
+    mesh = _ambient_mesh()
+    if mesh is None:
         return k
-    sizes = dict(zip(mesh.axis_names, mesh.devices.shape))
+    sizes = mesh.shape
     tp = "model" if "model" in sizes else None
     if tp is None or n_kv_heads % sizes[tp] == 0:
         return k  # heads shard fine; no CP needed
@@ -214,16 +212,12 @@ def constrain_kv_for_cache(k, n_kv_heads: int, seq_dim: int = 1):
 def constrain_logits(x):
     """Constrain logits (..., V) to batch-dp × vocab-tp when a mesh is
     ambient (same GSPMD-propagation insurance as ``embed_tokens``)."""
-    from jax.interpreters import pxla  # noqa: PLC0415
     from jax.sharding import PartitionSpec  # noqa: PLC0415
 
-    try:
-        mesh = pxla.thread_resources.env.physical_mesh
-        if mesh.empty:
-            return x
-    except Exception:  # noqa: BLE001
+    mesh = _ambient_mesh()
+    if mesh is None:
         return x
-    sizes = dict(zip(mesh.axis_names, mesh.devices.shape))
+    sizes = mesh.shape
     dp = tuple(a for a in mesh.axis_names if a in ("pod", "data"))
     tp = "model" if "model" in sizes else None
     spec = [None] * x.ndim
@@ -238,16 +232,12 @@ def constrain_residual(h):
     """Apply the residual-stream constraint to (B, S, D) activations."""
     if not _SEQUENCE_PARALLEL["on"] or h.ndim != 3:
         return h
-    from jax.interpreters import pxla  # noqa: PLC0415
     from jax.sharding import PartitionSpec  # noqa: PLC0415
 
-    try:
-        mesh = pxla.thread_resources.env.physical_mesh
-        if mesh.empty:
-            return h
-    except Exception:  # noqa: BLE001
+    mesh = _ambient_mesh()
+    if mesh is None:
         return h
-    sizes = dict(zip(mesh.axis_names, mesh.devices.shape))
+    sizes = mesh.shape
     dp = tuple(a for a in mesh.axis_names if a in ("pod", "data"))
     tp = "model" if "model" in sizes else None
     b_ok = dp and h.shape[0] % _grid(dp) == 0

@@ -9,8 +9,9 @@ batching argument the paper's decode cost model encodes (decode is
 bandwidth-bound; the roofline confirms it). Online softmax in VMEM scratch,
 one (g, D) output tile per (slot, kv-head).
 
-Valid-length masking reads ``length`` (B,1) from a tiny per-slot block —
-slots in a continuous-batching engine have ragged fill levels.
+Valid-length masking reads ``lengths`` (B,) from scalar-prefetch memory
+(SMEM) — slots in a continuous-batching engine have ragged fill levels, and
+a (1, 1) VMEM block of a (B, 1) array breaks the TPU tiling rule.
 """
 from __future__ import annotations
 
@@ -26,7 +27,7 @@ NEG_INF = -0.7 * float(jnp.finfo(jnp.float32).max)
 
 
 def _decode_kernel(
-    length_ref,                     # (1, 1) int32
+    length_ref,                     # (B,) int32 SMEM — scalar prefetch
     q_ref,                          # (1, 1, g, D)
     k_ref,                          # (1, 1, bk, D)
     v_ref,
@@ -37,6 +38,7 @@ def _decode_kernel(
     block_k: int,
     num_k_blocks: int,
 ):
+    ib = pl.program_id(0)
     ik = pl.program_id(2)
 
     @pl.when(ik == 0)
@@ -45,7 +47,7 @@ def _decode_kernel(
         m_ref[...] = jnp.full_like(m_ref, -jnp.inf)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    length = length_ref[0, 0]
+    length = length_ref[ib]
     k_start = ik * block_k
 
     @pl.when(k_start < length)
@@ -103,27 +105,31 @@ def decode_attention(
         k = jnp.pad(k, ((0, 0), (0, 0), (0, pad), (0, 0)))
         v = jnp.pad(v, ((0, 0), (0, 0), (0, pad), (0, 0)))
     qg = q.reshape(b, kv, g, d)
-    len2 = lengths.reshape(b, 1).astype(jnp.int32)
 
     kernel = functools.partial(
         _decode_kernel, scale=scale, block_k=bk, num_k_blocks=nk
     )
-    out = pl.pallas_call(
-        kernel,
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
         grid=(b, kv, nk),
         in_specs=[
-            pl.BlockSpec((1, 1), lambda ib, ih, ik: (ib, 0)),
-            pl.BlockSpec((1, 1, g, d), lambda ib, ih, ik: (ib, ih, 0, 0)),
-            pl.BlockSpec((1, 1, bk, d), lambda ib, ih, ik: (ib, ih, ik, 0)),
-            pl.BlockSpec((1, 1, bk, d), lambda ib, ih, ik: (ib, ih, ik, 0)),
+            pl.BlockSpec((1, 1, g, d), lambda ib, ih, ik, ln: (ib, ih, 0, 0)),
+            pl.BlockSpec((1, 1, bk, d), lambda ib, ih, ik, ln: (ib, ih, ik, 0)),
+            pl.BlockSpec((1, 1, bk, d), lambda ib, ih, ik, ln: (ib, ih, ik, 0)),
         ],
-        out_specs=pl.BlockSpec((1, 1, g, d), lambda ib, ih, ik: (ib, ih, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, kv, g, d), q.dtype),
+        out_specs=pl.BlockSpec(
+            (1, 1, g, d), lambda ib, ih, ik, ln: (ib, ih, 0, 0)
+        ),
         scratch_shapes=[
             pltpu.VMEM((g, d), jnp.float32),
             pltpu.VMEM((g,), jnp.float32),
             pltpu.VMEM((g,), jnp.float32),
         ],
+    )
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((b, kv, g, d), q.dtype),
         interpret=interpret,
-    )(len2, qg, k, v)
+    )(lengths.astype(jnp.int32), qg, k, v)
     return out.reshape(b, h, d)

@@ -28,10 +28,10 @@ from jax.experimental.pallas import tpu as pltpu
 def _rglru_kernel(
     a_ref,                           # (1, bs, br) f32 decay
     x_ref,                           # (1, bs, br) f32 gated input
-    h0_ref,                          # (1, br) f32 initial state
+    h0_ref,                          # (1, 1, br) f32 initial state
     o_ref,                           # (1, bs, br)
-    hN_ref,                          # (1, br) final state
-    carry_ref,                       # VMEM scratch (br,)
+    hN_ref,                          # (1, 1, br) final state
+    carry_ref,                       # VMEM scratch (1, br)
     *,
     block_s: int,
     num_s_blocks: int,
@@ -40,14 +40,14 @@ def _rglru_kernel(
 
     @pl.when(is_ == 0)
     def _init():
-        carry_ref[...] = h0_ref[0, :]
-
-    a = a_ref[0, :, :]               # (bs, br)
-    x = x_ref[0, :, :]
+        carry_ref[...] = h0_ref[0]
 
     def step(t, h):
-        h_new = a[t, :] * h + x[t, :]
-        o_ref[0, t, :] = h_new.astype(o_ref.dtype)
+        # one (1, br) row per time step, read and written through the refs
+        # (Mosaic lowers a dynamic row slice of a ref, not of a value)
+        row = pl.ds(t, 1)
+        h_new = a_ref[0, row, :] * h + x_ref[0, row, :]
+        o_ref[0, row, :] = h_new.astype(o_ref.dtype)
         return h_new
 
     h = jax.lax.fori_loop(0, block_s, step, carry_ref[...])
@@ -55,7 +55,7 @@ def _rglru_kernel(
 
     @pl.when(is_ == num_s_blocks - 1)
     def _finish():
-        hN_ref[0, :] = h.astype(hN_ref.dtype)
+        hN_ref[0] = h.astype(hN_ref.dtype)
 
 
 def rglru_scan(
@@ -76,6 +76,10 @@ def rglru_scan(
     if s % bs or r % br:
         raise ValueError(f"(S,R)=({s},{r}) must divide blocks ({bs},{br})")
     ns, nr = s // bs, r // br
+    # the state rides as (B, 1, R): its (1, br) blocks then satisfy the TPU
+    # tiling rule (last two block dims divisible by (8, 128) or equal to
+    # the array's), which a (1, br) block of a (B, R) array breaks
+    h0 = h0.reshape(b, 1, r)
 
     kernel = functools.partial(_rglru_kernel, block_s=bs, num_s_blocks=ns)
     out, h_fin = pl.pallas_call(
@@ -84,17 +88,17 @@ def rglru_scan(
         in_specs=[
             pl.BlockSpec((1, bs, br), lambda ib, ir, is_: (ib, is_, ir)),
             pl.BlockSpec((1, bs, br), lambda ib, ir, is_: (ib, is_, ir)),
-            pl.BlockSpec((1, br), lambda ib, ir, is_: (ib, ir)),
+            pl.BlockSpec((1, 1, br), lambda ib, ir, is_: (ib, 0, ir)),
         ],
         out_specs=[
             pl.BlockSpec((1, bs, br), lambda ib, ir, is_: (ib, is_, ir)),
-            pl.BlockSpec((1, br), lambda ib, ir, is_: (ib, ir)),
+            pl.BlockSpec((1, 1, br), lambda ib, ir, is_: (ib, 0, ir)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((b, s, r), jnp.float32),
-            jax.ShapeDtypeStruct((b, r), jnp.float32),
+            jax.ShapeDtypeStruct((b, 1, r), jnp.float32),
         ],
-        scratch_shapes=[pltpu.VMEM((br,), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((1, br), jnp.float32)],
         interpret=interpret,
     )(a.astype(jnp.float32), x.astype(jnp.float32), h0.astype(jnp.float32))
-    return out, h_fin
+    return out, h_fin.reshape(b, r)

@@ -1,4 +1,7 @@
 import os
+# A CPU-only dry run: pin the platform before JAX is imported, so it never
+# reaches for an accelerator that another process holds.
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = (
     "--xla_force_host_platform_device_count=512 " + os.environ.get("XLA_FLAGS", "")
 )
@@ -8,7 +11,7 @@ os.environ["XLA_FLAGS"] = (
 This proves the distribution config is coherent without hardware: 512
 placeholder CPU devices stand in for 2 pods × 256 chips. For each cell we
 
-    with mesh:
+    with jax.set_mesh(mesh):
         lowered  = jax.jit(step, in_shardings=...).lower(**input_specs(...))
         compiled = lowered.compile()
         compiled.memory_analysis()   # proves it fits 16 GB/chip
@@ -235,7 +238,7 @@ def run_cell(arch: str, shape: str, multi_pod: bool, overrides=None,
         "tag": tag,
     }
     try:
-        with mesh:
+        with jax.set_mesh(mesh):
             jitted = jax.jit(
                 fn, in_shardings=shards, out_shardings=out_shards,
                 donate_argnums=donate,

@@ -28,10 +28,13 @@ from pathlib import Path
 from typing import Dict, List, Optional
 
 from ..configs import SHAPES_BY_NAME, get_config
-from .mesh import HBM_BW, ICI_BW_PER_LINK, PEAK_FLOPS_BF16
+from .mesh import chip_peaks
 from .plan import WHISPER_DECODER_PROMPT
 
 RESULTS_DIR = Path(__file__).resolve().parents[3] / "experiments" / "dryrun"
+# the dry-run lowers for the v5e production mesh; its placeholder CPU
+# devices carry no device kind of their own
+PEAKS = chip_peaks("TPU v5 lite")
 
 
 @dataclass
@@ -72,7 +75,7 @@ class Roofline:
         """Achievable MFU bound: useful FLOPs / (bound time × peak)."""
         if self.bound_s <= 0:
             return 0.0
-        return self.model_flops_per_chip / (self.bound_s * PEAK_FLOPS_BF16)
+        return self.model_flops_per_chip / (self.bound_s * PEAKS.flops_bf16)
 
 
 def model_flops_per_chip(arch: str, shape: str, chips: int) -> float:
@@ -125,9 +128,9 @@ def roofline_for(result: dict) -> Optional[Roofline]:
         arch=result["arch"],
         shape=result["shape"],
         mesh=result["mesh"],
-        compute_s=flops / PEAK_FLOPS_BF16,
-        memory_s=hbm_bytes / HBM_BW,
-        collective_s=coll / ICI_BW_PER_LINK,
+        compute_s=flops / PEAKS.flops_bf16,
+        memory_s=hbm_bytes / PEAKS.hbm_bw,
+        collective_s=coll / PEAKS.ici_bw_per_link,
         model_flops_per_chip=mf,
         hlo_flops_per_chip=flops,
         fit_gb=hbm_bytes / 2**30,

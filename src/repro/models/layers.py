@@ -26,6 +26,7 @@ Logical axis vocabulary (mapped to mesh axes by sharding rules):
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 from typing import Any, Callable, Dict, Optional, Sequence, Tuple
 
@@ -49,30 +50,53 @@ class ParamDef:
             raise ValueError(f"shape {self.shape} vs axes {self.axes} rank mismatch")
 
 
-def _fan_in(shape: Tuple[int, ...]) -> int:
-    # For stacked layer weights the leading "layers" dim is not a fan-in.
-    return shape[-2] if len(shape) >= 2 else shape[-1]
+_STACKED_AXES = ("layers", "experts")
+
+
+def _fan_in(d: ParamDef) -> int:
+    """Width of the input a weight contracts: the product of its contracted
+    axes. Leading stacked axes ("layers", "experts") are not inputs. A
+    weight that reads the model width contracts it alone (``wq`` of shape
+    (L, d, H, hd) → d); a weight that writes the model width contracts
+    everything before it (``wo`` of shape (L, H, hd, d) → H·hd); any other
+    weight contracts its second-to-last axis."""
+    lead = 0
+    while lead < len(d.axes) and d.axes[lead] in _STACKED_AXES:
+        lead += 1
+    shape, axes = d.shape[lead:], d.axes[lead:]
+    if len(shape) < 2:
+        return d.shape[-1]
+    if axes[0] == "embed":
+        return shape[0]
+    if axes[-1] == "embed":
+        return math.prod(shape[:-1])
+    return shape[-2]
+
+
+@functools.partial(jax.jit, static_argnums=(1, 2, 3, 4))
+def _init_leaf(key, shape, dtype, init, scale):
+    """One parameter, generated on the device in its target dtype (one
+    compile per leaf shape; the float32 draw is fused into the cast, so no
+    float32 copy of a stacked weight is ever held)."""
+    if init == "zeros":
+        return jnp.zeros(shape, dtype)
+    if init == "ones":
+        return jnp.ones(shape, dtype)
+    return (jax.random.normal(key, shape, jnp.float32) * scale).astype(dtype)
 
 
 def init_params(rng: jax.Array, defs: Tree) -> Tree:
-    """Materialize a ParamDef tree into real arrays."""
+    """Materialize a ParamDef tree into real arrays, leaf by leaf."""
     leaves, treedef = jax.tree_util.tree_flatten(
         defs, is_leaf=lambda x: isinstance(x, ParamDef)
     )
     keys = jax.random.split(rng, len(leaves))
     out = []
     for key, d in zip(keys, leaves):
-        if d.init == "zeros":
-            out.append(jnp.zeros(d.shape, d.dtype))
-        elif d.init == "ones":
-            out.append(jnp.ones(d.shape, d.dtype))
-        else:
-            scale = d.scale
-            if scale is None:
-                scale = 1.0 if d.init == "embed" else 1.0 / math.sqrt(_fan_in(d.shape))
-            out.append(
-                (jax.random.normal(key, d.shape, jnp.float32) * scale).astype(d.dtype)
-            )
+        scale = d.scale
+        if scale is None:
+            scale = 1.0 if d.init == "embed" else 1.0 / math.sqrt(_fan_in(d))
+        out.append(_init_leaf(key, tuple(d.shape), d.dtype, d.init, float(scale)))
     return jax.tree_util.tree_unflatten(treedef, out)
 
 

@@ -367,6 +367,27 @@ def _mixed_dispatch(
     )
 
 
+def serving_jits(model, sampler, eos_id):
+    """The engine's jitted dispatches — the paged chunk round, the paged
+    mixed round, and fused decode (both layouts) — each donating the KV
+    cache. Exposed so a launcher can compile them against abstract shapes
+    (``.lower().compile()``) and size the pool from ``memory_analysis()``
+    before allocating it."""
+    chunk = jax.jit(
+        lambda p, t, c, s, st, ln: model.prefill_chunk(p, t, c, s, st, ln),
+        donate_argnums=(2,),
+    )
+    mixed = jax.jit(
+        functools.partial(_mixed_dispatch, model, sampler), donate_argnums=(2,)
+    )
+    fused = jax.jit(
+        functools.partial(_fused_decode, model, sampler, eos_id),
+        static_argnums=(0,),
+        donate_argnums=(3,),
+    )
+    return chunk, mixed, fused
+
+
 class Engine:
     def __init__(
         self,
@@ -404,19 +425,18 @@ class Engine:
                 "prefix_cache requires kv_layout='paged' — dense layout has "
                 "no page identity to share"
             )
+        # ONE decode path for both layouts and every horizon: a fused
+        # K-iteration on-device loop (K static → one executable per horizon
+        # bucket). The cache is donated, so K-step decode updates it in
+        # place; tokens stay on device until the horizon boundary.
+        self._chunk_jit, self._mixed_jit, self._fused_jit = serving_jits(
+            model, sampler, config.eos_id
+        )
         if config.kv_layout == "paged":
             self.slots: Any = PagedSlotManager(
                 model, config.n_slots, config.max_len,
                 config.page_size, config.num_pages,
                 prefix_cache=config.prefix_cache,
-            )
-            self._chunk_jit = jax.jit(
-                lambda p, t, c, s, st, ln: model.prefill_chunk(p, t, c, s, st, ln),
-                donate_argnums=(2,),
-            )
-            self._mixed_jit = jax.jit(
-                functools.partial(_mixed_dispatch, model, sampler),
-                donate_argnums=(2,),
             )
         elif config.kv_layout == "dense":
             self.slots = SlotManager(model, config.n_slots, config.max_len)
@@ -431,17 +451,6 @@ class Engine:
         self._base_key = (
             jax.random.key(config.sample_seed)
             if getattr(sampler, "stochastic", False) else None
-        )
-        # ONE decode path for both layouts and every horizon: a fused
-        # K-iteration on-device loop (K static → one executable per horizon
-        # bucket). The cache is donated, so K-step decode updates it in
-        # place; tokens stay on device until the horizon boundary.
-        self._fused_jit = jax.jit(
-            functools.partial(
-                _fused_decode, model, sampler, config.eos_id
-            ),
-            static_argnums=(0,),
-            donate_argnums=(3,),
         )
         self.pending_token = np.zeros(config.n_slots, dtype=np.int32)
         # Device-side copy of pending tokens, carried between consecutive
@@ -940,30 +949,70 @@ class Engine:
             for st in self._chunking.values()
         )
 
-    def _run_chunk_round(self):
-        """One chunk round over every mid-prefill slot; returns
-        (duration, chunk_tokens, finished_slots, busy, busy_partial)."""
-        states = [self._chunking[s] for s in sorted(self._chunking)]
+    def _dispatch_chunks(self, rows: Sequence[Tuple[int, np.ndarray, int]]):
+        """One chunk-round dispatch over ``rows`` of (slot, chunk tokens,
+        start offset), padded to a row bucket. Returns (logits, per-row
+        real lengths, duration)."""
         c = self.cfg.prefill_chunk
-        n_pad = _bucket(len(states), self.cfg.prefill_req_buckets)
+        n_pad = _bucket(len(rows), self.cfg.prefill_req_buckets)
         tokens = np.zeros((n_pad, c), dtype=np.int32)
         # pad rows point one past the last slot: their (len-0) writes drop
         slot_ids = np.full(n_pad, self.cfg.n_slots, dtype=np.int32)
         starts = np.zeros(n_pad, dtype=np.int32)
         lens = np.zeros(n_pad, dtype=np.int32)
-        for i, st in enumerate(states):
-            n = min(c, st.remaining)
-            tokens[i, :n] = st.prompt[st.done : st.done + n]
-            slot_ids[i] = st.slot
-            starts[i] = st.done
-            lens[i] = n
+        for i, (slot, toks, start) in enumerate(rows):
+            tokens[i, : len(toks)] = toks
+            slot_ids[i] = slot
+            starts[i] = start
+            lens[i] = len(toks)
         t0 = time.perf_counter()
         logits, self.slots.cache = self._chunk_jit(
             self.params, jnp.asarray(tokens), self.slots.cache,
             jnp.asarray(slot_ids), jnp.asarray(starts), jnp.asarray(lens),
         )
         logits.block_until_ready()
-        dt = (time.perf_counter() - t0) / self.speed_factor
+        return logits, lens, time.perf_counter() - t0
+
+    def prefill_logits(self, prompts: Sequence[np.ndarray]) -> np.ndarray:
+        """Last-token logits (len(prompts), V) of each prompt through the
+        engine's own paged chunked prefill — the chunk-round program, on
+        free slots of the live pool, whose pages are freed afterwards. For
+        use between serves: it is how the serving path's numerics are
+        checked against a cache-free reference forward."""
+        if self.cfg.kv_layout != "paged":
+            raise ValueError("prefill_logits needs kv_layout='paged'")
+        slots = self.slots.free_slots[: len(prompts)]
+        if len(slots) < len(prompts):
+            raise RuntimeError(
+                f"{len(prompts)} prompts but {len(slots)} free slots"
+            )
+        for slot, prompt in zip(slots, prompts):
+            self.slots.reserve(slot, len(prompt))
+        c = self.cfg.prefill_chunk
+        out: List[Optional[np.ndarray]] = [None] * len(prompts)
+        for start in range(0, max(len(p) for p in prompts), c):
+            live = [i for i, p in enumerate(prompts) if start < len(p)]
+            logits, _, _ = self._dispatch_chunks(
+                [(slots[i], prompts[i][start : start + c], start) for i in live]
+            )
+            logits = np.asarray(logits)
+            for row, i in enumerate(live):
+                if start + c >= len(prompts[i]):
+                    out[i] = logits[row]
+        for slot in slots:
+            self.slots.free_pages_of(slot)
+        return np.stack(out)
+
+    def _run_chunk_round(self):
+        """One chunk round over every mid-prefill slot; returns
+        (duration, chunk_tokens, finished_slots, busy, busy_partial)."""
+        states = [self._chunking[s] for s in sorted(self._chunking)]
+        c = self.cfg.prefill_chunk
+        logits, lens, dt = self._dispatch_chunks([
+            (st.slot, st.prompt[st.done : st.done + c], st.done)
+            for st in states
+        ])
+        dt /= self.speed_factor
         first = self._sample_first(logits, [st.req.rid for st in states])
         self._dev_pending = None          # prefill rewrites pending tokens
         busy: Dict[int, int] = {}

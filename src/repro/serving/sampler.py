@@ -61,10 +61,17 @@ class Sampler:
 
 @dataclasses.dataclass(frozen=True)
 class GreedySampler(Sampler):
+    """Argmax. A row whose logits are not all finite samples token id V,
+    one past the vocabulary, so a numerical fault shows in the token stream
+    (and poisons the slot's next embedding) instead of passing as a
+    plausible token."""
+
     def __call__(
         self, logits: jax.Array, keys: Optional[jax.Array] = None
     ) -> jax.Array:
-        return jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        tok = jnp.argmax(logits, axis=-1)
+        finite = jnp.all(jnp.isfinite(logits), axis=-1)
+        return jnp.where(finite, tok, logits.shape[-1]).astype(jnp.int32)
 
 
 @dataclasses.dataclass(frozen=True)

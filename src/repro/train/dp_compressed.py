@@ -47,8 +47,6 @@ def make_dp_train_step(
         new_params, new_opt, metrics = adamw_update(grads, opt_state, params, opt_cfg)
         return new_params, new_opt, error_fb, dict(metrics, loss=loss)
 
-    from jax.experimental.shard_map import shard_map
-
     rep = P()
     batch_spec = P(dp_axis)
 
@@ -56,12 +54,12 @@ def make_dp_train_step(
         return {k: batch_spec for k in batch}
 
     def step(params, opt_state, error_fb, batch):
-        return shard_map(
+        return jax.shard_map(
             shard_body,
             mesh=mesh,
             in_specs=(rep, rep, rep, {k: batch_spec for k in batch}),
             out_specs=(rep, rep, rep, rep),
-            check_rep=False,
+            check_vma=False,
         )(params, opt_state, error_fb, batch)
 
     return jax.jit(step, donate_argnums=(0, 1, 2))

@@ -11,6 +11,9 @@ import pytest
 
 def _run_fake_devices(script: str, n_devices: int = 8, timeout: int = 360) -> str:
     env = {
+        # the children compile for fake CPU devices and must never take an
+        # accelerator the parent or another test process may hold
+        "JAX_PLATFORMS": "cpu",
         "XLA_FLAGS": f"--xla_force_host_platform_device_count={n_devices}",
         "PYTHONPATH": "src",
         "PATH": "/usr/bin:/bin",
@@ -33,9 +36,10 @@ def test_spec_builder_shape_checks():
     """Pure sharding-rule logic (no mesh state needed beyond construction)."""
     out = _run_fake_devices("""
         import jax
+        from repro.launch.mesh import make_mesh
         from jax.sharding import PartitionSpec as P
         from repro.distributed.sharding import ShardingConfig, _spec_for
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        mesh = make_mesh((4, 2), ("data", "model"))
         scfg = ShardingConfig()
         rules = scfg.rules()
         # weight (embed, mlp): fsdp over data + tp over model
@@ -61,6 +65,7 @@ def test_small_mesh_train_and_serve_lower():
     decode steps lower+compile with the production sharding rules."""
     out = _run_fake_devices("""
         import jax, jax.numpy as jnp
+        from repro.launch.mesh import make_mesh
         from repro.configs import get_smoke_config
         from repro.models.registry import get_model
         from repro.models.layers import abstract_params, logical_specs
@@ -69,7 +74,7 @@ def test_small_mesh_train_and_serve_lower():
         from repro.train.optimizer import AdamWConfig, abstract_opt_state
         from repro.train.train_step import make_train_step
 
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        mesh = make_mesh((4, 2), ("data", "model"))
         scfg = ShardingConfig()
         cfg = get_smoke_config("qwen3_8b")
         model = get_model(cfg)
@@ -84,7 +89,7 @@ def test_small_mesh_train_and_serve_lower():
                  "labels": jax.ShapeDtypeStruct((8, 32), jnp.int32)}
         bs = jax.sharding.NamedSharding(mesh, jax.sharding.PartitionSpec("data"))
         step = make_train_step(model, AdamWConfig(), microbatches=2)
-        with mesh:
+        with jax.set_mesh(mesh):
             c = jax.jit(step, in_shardings=(ps, osd, {"tokens": bs, "labels": bs}),
                         donate_argnums=(0, 1)).lower(ap, oa, batch).compile()
         assert c.memory_analysis().temp_size_in_bytes > 0
@@ -94,7 +99,7 @@ def test_small_mesh_train_and_serve_lower():
         tok = jax.ShapeDtypeStruct((8,), jnp.int32)
         ts = jax.sharding.NamedSharding(mesh, jax.sharding.PartitionSpec("data"))
         fn = lambda p, t, c: model.decode_step(p, t, c)
-        with mesh:
+        with jax.set_mesh(mesh):
             c2 = jax.jit(fn, in_shardings=(ps, ts, cs), donate_argnums=(2,)).lower(
                 ap, tok, cache).compile()
         print("OK")
@@ -108,13 +113,14 @@ def test_compressed_dp_train_step_numerics():
     uncompressed path."""
     out = _run_fake_devices("""
         import jax, jax.numpy as jnp, numpy as np
+        from repro.launch.mesh import make_mesh
         from repro.configs import get_smoke_config
         from repro.models.registry import get_model
         from repro.models.layers import init_params
         from repro.train.dp_compressed import make_dp_train_step, init_error_feedback
         from repro.train.optimizer import AdamWConfig, adamw_init
 
-        mesh = jax.make_mesh((8,), ("data",))
+        mesh = make_mesh((8,), ("data",))
         cfg = get_smoke_config("granite_3_8b")
         import dataclasses
         cfg = dataclasses.replace(cfg, dtype="float32")
@@ -153,6 +159,7 @@ def test_elastic_remesh_and_restore(tmp_path):
     values identical, shardings valid on the new mesh."""
     out = _run_fake_devices(f"""
         import jax, jax.numpy as jnp, numpy as np
+        from repro.launch.mesh import make_mesh
         from repro.checkpoint import save_checkpoint
         from repro.configs import get_smoke_config
         from repro.distributed.elastic import build_mesh, remesh_plan, reshard_restore
@@ -164,7 +171,7 @@ def test_elastic_remesh_and_restore(tmp_path):
         model = get_model(cfg)
         defs = model.param_defs()
         params = init_params(jax.random.key(0), defs)
-        mesh1 = jax.make_mesh((4, 2), ("data", "model"))
+        mesh1 = make_mesh((4, 2), ("data", "model"))
         save_checkpoint(r"{tmp_path}", 7, params)
 
         plan = remesh_plan((4, 2), ("data", "model"), n_healthy=4)

@@ -257,6 +257,15 @@ def test_sampler_objects_jit_and_key_threading():
     )
 
 
+def test_greedy_marks_non_finite_rows_out_of_vocabulary():
+    logits = np.random.default_rng(1).normal(size=(4, 32)).astype(np.float32)
+    logits[1, 7] = np.nan
+    logits[3, 0] = np.inf
+    out = np.asarray(jax.jit(GreedySampler())(jnp.asarray(logits)))
+    assert out[1] == 32 and out[3] == 32
+    np.testing.assert_array_equal(out[[0, 2]], np.argmax(logits[[0, 2]], axis=-1))
+
+
 # --------------------------------------------------------------------------- #
 # Horizon pricing + per-horizon cost model                                    #
 # --------------------------------------------------------------------------- #

@@ -230,6 +230,12 @@ class StageRecord:
     # True when prefill work was pending or in flight while this stage ran —
     # the "during a prefill burst" tag the latency benchmarks slice on.
     burst: bool = False
+    # Engine stages only: wall seconds of each phase of the serve step that
+    # ran this stage (``schedule``, ``kv``, ``prepare``, ``launch``,
+    # ``wait``, ``commit``; launch + wait is the stage's measured time), and
+    # the eager device writes the KV pool dispatched in that step.
+    host: Dict[str, float] = field(default_factory=dict)
+    pool_writes: int = 0
 
     @property
     def duration(self) -> float:

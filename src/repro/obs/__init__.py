@@ -38,6 +38,7 @@ from .lifecycle import (
     request_timelines,
 )
 from .metrics import MetricDeclarationError, MetricSpec, MetricsRegistry
+from .phases import Phases
 from .spans import SpanEvent, SpanLog
 
 __all__ = [
@@ -48,6 +49,7 @@ __all__ = [
     "CAPACITY_CLASSES", "capacity_attribution", "capacity_table",
     "check_capacity_conservation", "lifecycle_table", "request_timelines",
     "perfetto_trace", "write_trace",
+    "Phases",
 ]
 
 

@@ -83,6 +83,7 @@ from ..core.types import (
     StageKind,
     StageRecord,
 )
+from ..obs.phases import Phases
 from .kv_slots import PagedSlotManager, SlotManager
 from .overload import OverloadPolicy
 from .profiler import OnlineProfiler
@@ -353,7 +354,7 @@ def _fused_decode(
     )
 
 
-def _mixed_dispatch(
+def _mixed_round(
     model, sampler,
     params, dec_tokens, cache, chunk_tokens, chunk_slots, chunk_starts,
     chunk_lens, dec_active, rids, token_idx, sample_rows, base_key,
@@ -367,23 +368,41 @@ def _mixed_dispatch(
     )
 
 
+def _chunk_round(model, params, tokens, cache, slots, starts, lens):
+    """Jit target for the paged chunk round."""
+    return model.prefill_chunk(params, tokens, cache, slots, starts, lens)
+
+
+def _dense_prefill(model, params, tokens, cache, lengths):
+    """Jit target for the dense layout's whole-prompt prefill."""
+    return model.prefill(params, tokens, cache, lengths=lengths)
+
+
+def _named_jit(name: str, fn, *bound, **jit_kw):
+    """``jax.jit`` of ``fn`` with its leading arguments ``bound``, compiled
+    as program ``jit_<name>``: the name profiles, compile logs and
+    ``memory_analysis`` show. The name goes on the partial itself, not
+    through ``functools.update_wrapper``, whose ``__wrapped__`` would show
+    jit ``fn``'s whole signature, bound arguments included."""
+    target = functools.partial(fn, *bound)
+    target.__name__ = name
+    return jax.jit(target, **jit_kw)
+
+
 def serving_jits(model, sampler, eos_id):
     """The engine's jitted dispatches — the paged chunk round, the paged
     mixed round, and fused decode (both layouts) — each donating the KV
-    cache. Exposed so a launcher can compile them against abstract shapes
+    cache and named ``chunk_round``, ``mixed_round`` and ``fused_decode``.
+    Exposed so a launcher can compile them against abstract shapes
     (``.lower().compile()``) and size the pool from ``memory_analysis()``
     before allocating it."""
-    chunk = jax.jit(
-        lambda p, t, c, s, st, ln: model.prefill_chunk(p, t, c, s, st, ln),
-        donate_argnums=(2,),
+    chunk = _named_jit("chunk_round", _chunk_round, model, donate_argnums=(2,))
+    mixed = _named_jit(
+        "mixed_round", _mixed_round, model, sampler, donate_argnums=(2,)
     )
-    mixed = jax.jit(
-        functools.partial(_mixed_dispatch, model, sampler), donate_argnums=(2,)
-    )
-    fused = jax.jit(
-        functools.partial(_fused_decode, model, sampler, eos_id),
-        static_argnums=(0,),
-        donate_argnums=(3,),
+    fused = _named_jit(
+        "fused_decode", _fused_decode, model, sampler, eos_id,
+        static_argnums=(0,), donate_argnums=(3,),
     )
     return chunk, mixed, fused
 
@@ -440,9 +459,8 @@ class Engine:
             )
         elif config.kv_layout == "dense":
             self.slots = SlotManager(model, config.n_slots, config.max_len)
-            self._prefill_jit = jax.jit(
-                lambda p, t, c, l: model.prefill(p, t, c, lengths=l),
-                donate_argnums=(2,),
+            self._prefill_jit = _named_jit(
+                "dense_prefill", _dense_prefill, model, donate_argnums=(2,)
             )
         else:
             raise ValueError(f"unknown kv_layout {config.kv_layout!r}")
@@ -519,6 +537,9 @@ class Engine:
         # → finish_serve); ``serve()`` owns it for closed-loop runs, a
         # Fleet drives it directly for interleaved multi-replica serving
         self._sv: Optional[_ServeSession] = None
+        # wall-clock phases of the current serve step (StageRecord.host and
+        # the engine.<phase> profiler spans)
+        self._phases = Phases()
 
     # ------------------------------------------------------------------ #
     def _prompt_tokens(self, req: Request) -> np.ndarray:
@@ -583,11 +604,15 @@ class Engine:
             lengths[i] = r.n_prefill
         cache = self.model.cache_init(n_pad, s_pad)
         t0 = time.perf_counter()
+        self._phases.mark("launch", t0)
         logits, pref_cache = self._prefill_jit(
             self.params, jnp.asarray(tokens), cache, jnp.asarray(lengths)
         )
+        self._phases.mark("wait")
         logits.block_until_ready()
-        dt = (time.perf_counter() - t0) / self.speed_factor
+        t1 = time.perf_counter()
+        self._phases.mark("commit", t1)
+        dt = (t1 - t0) / self.speed_factor
         first = self._sample_first(logits, [r.rid for r in reqs])
         self._dev_pending = None          # prefill rewrites pending tokens
         # scatter only the real rows (the batch was padded to a bucket)
@@ -966,12 +991,16 @@ class Engine:
             starts[i] = start
             lens[i] = len(toks)
         t0 = time.perf_counter()
+        self._phases.mark("launch", t0)
         logits, self.slots.cache = self._chunk_jit(
             self.params, jnp.asarray(tokens), self.slots.cache,
             jnp.asarray(slot_ids), jnp.asarray(starts), jnp.asarray(lens),
         )
+        self._phases.mark("wait")
         logits.block_until_ready()
-        return logits, lens, time.perf_counter() - t0
+        t1 = time.perf_counter()
+        self._phases.mark("commit", t1)
+        return logits, lens, t1 - t0
 
     def prefill_logits(self, prompts: Sequence[np.ndarray]) -> np.ndarray:
         """Last-token logits (len(prompts), V) of each prompt through the
@@ -1135,6 +1164,7 @@ class Engine:
             else jnp.asarray(self.pending_token)
         )
         t0 = time.perf_counter()
+        self._phases.mark("launch", t0)
         sampled, self.slots.cache = self._mixed_jit(
             self.params, pending, self.slots.cache,
             jnp.asarray(chunk_tokens), jnp.asarray(chunk_slots),
@@ -1142,8 +1172,11 @@ class Engine:
             jnp.asarray(dec_active), jnp.asarray(rids),
             jnp.asarray(token_idx), jnp.asarray(sample_rows), self._base_key,
         )
+        self._phases.mark("wait")
         sampled = np.asarray(sampled)      # the ONE host sync for this round
-        dt = (time.perf_counter() - t0) / self.speed_factor
+        t1 = time.perf_counter()
+        self._phases.mark("commit", t1)
+        dt = (t1 - t0) / self.speed_factor
         self._dev_pending = None           # pending rebuilt from host below
 
         finished_decode: List[int] = []
@@ -1332,6 +1365,7 @@ class Engine:
             else jnp.asarray(self.pending_token)
         )
         t0 = time.perf_counter()
+        self._phases.mark("launch", t0)
         token_block, emitted_k, active_out, last_tok, self.slots.cache = (
             self._fused_jit(
                 k, self.params, pending, self.slots.cache,
@@ -1339,11 +1373,14 @@ class Engine:
                 jnp.asarray(emit0), self._base_key,
             )
         )
+        self._phases.mark("wait")
         # the ONE host sync for this horizon: everything the scheduler needs
         block = np.asarray(token_block)                    # (K, n_slots)
         emitted_k = np.asarray(emitted_k)
         active_out = np.asarray(active_out)
-        dt = (time.perf_counter() - t0) / self.speed_factor
+        t1 = time.perf_counter()
+        self._phases.mark("commit", t1)
+        dt = (t1 - t0) / self.speed_factor
         self._dev_pending = last_tok      # stays device-resident across stages
         self.decode_dispatches += 1
         finished: List[int] = []
@@ -1678,7 +1715,32 @@ class Engine:
         * ``"idle"`` — pending work exists but nothing can run and no
           arrival is known to wait for (closed-loop callers treat this as a
           deadlock; a fleet decides what happens next).
+
+        A step that runs a stage stamps the stage's record with the wall
+        seconds of its phases (``StageRecord.host``: ``schedule``, ``kv``,
+        ``prepare``, ``launch``, ``wait``, ``commit``, in that order; a retry
+        after an eviction adds to them) and the KV pool's eager device
+        writes in the step (``StageRecord.pool_writes``). Each phase is also
+        a ``jax.profiler.TraceAnnotation`` named ``engine.<phase>``.
         """
+        trace = self._sv.trace
+        n_stages = len(trace.stages)
+        paged = self.cfg.kv_layout == "paged"
+        writes = self.slots.device_writes if paged else 0
+        self._phases.start()
+        self._phases.mark("schedule")
+        try:
+            status = self._serve_step()
+        finally:
+            host = self._phases.stop()
+        if len(trace.stages) > n_stages:
+            rec = trace.stages[-1]
+            rec.host = host
+            if paged:
+                rec.pool_writes = self.slots.device_writes - writes
+        return status
+
+    def _serve_step(self) -> str:
         sv = self._sv
         cfg = self.cfg
         paged = cfg.kv_layout == "paged"
@@ -1687,7 +1749,9 @@ class Engine:
         request_scheduler = sv.scheduler
         iteration_policy = sv.policy
         trace = sv.trace
+        ph = self._phases
         for _attempt in range(4):
+            ph.mark("schedule")
             if sv.stages_run >= cfg.max_stages:
                 raise RuntimeError("max_stages exceeded")
             t = sv.t
@@ -1801,6 +1865,7 @@ class Engine:
                 ]
                 share = fitting[-1] if fitting else cfg.mixed_token_buckets[0]
                 plan, admitted = self._plan_mixed_round(pairs, share)
+                ph.mark("kv")
                 if admitted:
                     new_pairs = [(c, r) for c, r, _ in admitted]
                     self._commit_pairs(new_pairs)
@@ -1822,6 +1887,7 @@ class Engine:
                     ]
                     if not self.slots.active_slots:
                         continue   # every decode lane was evicted — re-plan
+                ph.mark("prepare")
                 (
                     dt, fin_decode, decode_tok, chunk_tok, fin_chunks,
                     busy, busy_partial,
@@ -1860,10 +1926,12 @@ class Engine:
                 # carry dead decode lanes — run the plain chunk round (same
                 # per-row math and jit shapes, honest prefill timing for
                 # the cost model and straggler predictor)
+                ph.mark("kv")
                 if pairs:
                     self._commit_pairs(pairs)
                     sv.bin_index += 1
                     self._start_chunked_batch(pairs, sv.bin_index, t)
+                ph.mark("prepare")
                 dt, tok, finished, busy, busy_partial = self._run_chunk_round()
                 if active:
                     # decoders froze for the whole preempting chunk round
@@ -1887,8 +1955,10 @@ class Engine:
                     )
                 self._finish_prefills(finished, clients, sv.t)
             elif do_prefill and candidate:
+                ph.mark("kv")
                 self._commit_pairs(pairs)
                 sv.bin_index += 1
+                ph.mark("prepare")
                 dt, tok = self._run_prefill_stage(pairs)
                 if active:
                     self.prefill_stall_time += dt
@@ -1929,6 +1999,7 @@ class Engine:
                             self._obs_complete(req, sv.t, client.cid)
             elif active:
                 k = self._choose_horizon(decision.horizon)
+                ph.mark("kv")
                 if paged and cfg.page_reserve != "upfront":
                     # a pinned decode_horizon must run the K it asked for, so
                     # only policy-driven horizons may shrink before evicting
@@ -1937,6 +2008,7 @@ class Engine:
                     )
                     if not self.slots.active_slots:
                         continue   # every decode lane was evicted — re-plan
+                ph.mark("prepare")
                 dt, finished, tokens = self._run_decode_stage(k)
                 # the stage right after a preempting prefill carries the
                 # stall in its first-token gap — it belongs to the burst

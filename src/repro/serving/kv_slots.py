@@ -503,6 +503,10 @@ class PagedSlotManager:
         self.peak_pages = 0
         self.shared_pages_peak = 0
         self.cow_copies = 0
+        # eager device writes dispatched to the pool (block-table rows,
+        # lengths, page copies), one per scatter: the engine stamps each
+        # step's count on its StageRecord.pool_writes
+        self.device_writes = 0
         # observability hooks, set by the owning engine when a serve opts
         # in (EngineConfig.observe); obs_now is refreshed each serve step
         # so COW instants land at the engine's current virtual time
@@ -539,6 +543,7 @@ class PagedSlotManager:
         self.cache["block_tables"] = (
             self.cache["block_tables"].at[slot].set(jnp.asarray(row))
         )
+        self.device_writes += 1
 
     def _alloc(self, n_pages: int) -> List[int]:
         """Allocate fresh pages, evicting index-only cached pages on demand
@@ -579,6 +584,7 @@ class PagedSlotManager:
         self.cache["v"] = self.cache["v"].at[:, :, dst].set(
             self.cache["v"][:, :, src]
         )
+        self.device_writes += 2
         self.cow_copies += 1
         if self.obs is not None:
             self.obs.instant(
@@ -630,6 +636,7 @@ class PagedSlotManager:
         )
         self._mirror_row(slot)
         self.cache["length"] = self.cache["length"].at[slot].set(int(cached))
+        self.device_writes += 1
         return int(cached)
 
     def publish_prefix(self, slot: int, prompt: np.ndarray) -> int:
@@ -689,6 +696,7 @@ class PagedSlotManager:
             self.tables[slot] = []
         self.cache["block_tables"] = self.cache["block_tables"].at[slot].set(-1)
         self.cache["length"] = self.cache["length"].at[slot].set(0)
+        self.device_writes += 2
 
     # -- page-copy migration (live cross-engine slot transfer) ---------- #
     def export_pages(
@@ -755,6 +763,7 @@ class PagedSlotManager:
         self.peak_pages = max(self.peak_pages, self.allocator.num_used)
         self._mirror_row(slot)
         self.cache["length"] = self.cache["length"].at[slot].set(int(kv_length))
+        self.device_writes += 3
         return pages
 
     def check_block_table_mirror(self) -> None:

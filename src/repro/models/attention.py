@@ -271,8 +271,9 @@ def attention_any(
 
 def attention_paged(
     q: jax.Array,                     # (B, Sq, H, D)
-    k_pages: jax.Array,               # (KV, P, bs, D) — one layer's page pool
-    v_pages: jax.Array,
+    k_pool: jax.Array,                # (L, P, bs, KV, D) — the whole pool
+    v_pool: jax.Array,
+    layer: jax.Array,                 # scalar int32 — the layer read
     block_tables: jax.Array,          # (B, MB) int32; -1 = unallocated
     *,
     q_positions: jax.Array,           # (B, Sq) int32
@@ -281,7 +282,7 @@ def attention_paged(
     causal: bool = True,
     scale: Optional[float] = None,
 ) -> jax.Array:
-    """Attention against a paged KV cache (reference path).
+    """Attention against one layer of a paged KV pool (reference path).
 
     Gathers each slot's pages into its logical (MB·bs) sequence and masks
     unallocated/past-length positions via the shared position-based scheme —
@@ -290,9 +291,9 @@ def attention_paged(
     chunk) and decode (Sq = 1) behind the paged cache-layout flag."""
     from .cache import paged_gather_kv, paged_key_positions
 
-    k_ctx, v_ctx = paged_gather_kv(k_pages, v_pages, block_tables)
+    k_ctx, v_ctx = paged_gather_kv(k_pool, v_pool, layer, block_tables)
     k_positions = paged_key_positions(
-        block_tables, valid_lengths, k_pages.shape[2]
+        block_tables, valid_lengths, k_pool.shape[2]
     )
     return attention(
         q, k_ctx, v_ctx,

@@ -8,14 +8,16 @@ the dry-run. Three attention cache styles:
   * ring cache  — (L, B, W, KV, D) for sliding-window attention; slot
                   ``length % W``; per-slot absolute positions are stored so
                   masking stays position-based (see models.attention)
-  * paged cache — (L, KV, P, bs, D): a global pool of P pages of ``bs``
+  * paged cache — (L, P, bs, KV, D): a global pool of P pages of ``bs``
                   tokens each, indirected through a per-slot ``block_tables``
                   row ((n_slots, MB) int32; -1 = unallocated). Slots own only
                   the pages their live tokens occupy, so KV memory scales
-                  with tokens-in-use instead of n_slots × max_len. The page
-                  axis precedes the token axis with KV outermost so the
-                  Pallas decode kernel's (bs, D) page blocks are tiled
-                  contiguously (see kernels.paged_decode_attention).
+                  with tokens-in-use instead of n_slots × max_len. The pool
+                  is token-major: a token's K (or V) is one (KV, D) row at
+                  flat index page·bs + offset of its layer, the layout
+                  attention reads, so a gathered block table needs no
+                  transpose and the serving programs' layer scans scatter
+                  into the carried pool in place (see models.transformer).
 
 Recurrent families (xLSTM, RG-LRU) keep per-layer state tensors instead; see
 their modules. ``length`` is a scalar int32 shared by all layers.
@@ -182,8 +184,8 @@ def paged_cache_shape(
 ) -> Dict[str, jax.ShapeDtypeStruct]:
     f = jax.ShapeDtypeStruct
     return {
-        "k": f((n_layers, kv_heads, num_pages, page_size, head_dim), dtype),
-        "v": f((n_layers, kv_heads, num_pages, page_size, head_dim), dtype),
+        "k": f((n_layers, num_pages, page_size, kv_heads, head_dim), dtype),
+        "v": f((n_layers, num_pages, page_size, kv_heads, head_dim), dtype),
         "block_tables": f((n_slots, max_pages_per_slot), jnp.int32),
         "length": f((n_slots,), jnp.int32),
     }
@@ -195,49 +197,54 @@ def paged_cache_init(
     dtype=jnp.bfloat16,
 ) -> Dict[str, jax.Array]:
     return {
-        "k": jnp.zeros((n_layers, kv_heads, num_pages, page_size, head_dim), dtype),
-        "v": jnp.zeros((n_layers, kv_heads, num_pages, page_size, head_dim), dtype),
+        "k": jnp.zeros((n_layers, num_pages, page_size, kv_heads, head_dim), dtype),
+        "v": jnp.zeros((n_layers, num_pages, page_size, kv_heads, head_dim), dtype),
         "block_tables": jnp.full((n_slots, max_pages_per_slot), -1, jnp.int32),
         "length": jnp.zeros((n_slots,), jnp.int32),
     }
 
 
 def paged_cache_write(
-    k_layer: jax.Array,       # (KV, P, bs, D) — one layer's page pool
-    v_layer: jax.Array,
+    k_pool: jax.Array,        # (L, P, bs, KV, D) — the whole page pool
+    v_pool: jax.Array,
+    layer: jax.Array,         # scalar int32 — the layer written
     k_new: jax.Array,         # (B, S, KV, D) — token t of row b at position
     v_new: jax.Array,         #                 starts[b] + t
     block_tables: jax.Array,  # (B, MB) int32; -1 = unallocated
     starts: jax.Array,        # (B,) int32 — first token's absolute position
     lens: jax.Array,          # (B,) int32 — valid tokens per row (≤ S)
 ) -> Tuple[jax.Array, jax.Array]:
-    """Scatter a prefill chunk into the page pool through the block table.
+    """Scatter rows of tokens into ``layer`` of the page pool through the
+    block table: each token is one (KV, D) row at flat index page·bs +
+    offset. The pools come back whole, so a layer scan that carries them
+    updates them in place.
 
     Rows may sit at different offsets (ragged chunked prefill); tokens beyond
     ``lens`` or mapping to an unallocated page are dropped, so padded batch
     rows can point at any table row without corrupting it.
     """
-    kv, p, bs, d = k_layer.shape
+    n_layers, p, bs, kv, d = k_pool.shape
     b, s = k_new.shape[:2]
     mb = block_tables.shape[1]
     pos = starts[:, None] + jnp.arange(s, dtype=jnp.int32)[None, :]   # (B, S)
     blk = pos // bs
     page = jnp.take_along_axis(block_tables, jnp.clip(blk, 0, mb - 1), axis=1)
     valid = (jnp.arange(s)[None, :] < lens[:, None]) & (page >= 0) & (blk < mb)
-    flat = jnp.where(valid, page * bs + pos % bs, p * bs)             # OOB → drop
-    flat = flat.reshape(-1)
-    kf = k_layer.reshape(kv, p * bs, d)
-    vf = v_layer.reshape(kv, p * bs, d)
-    k_rows = k_new.astype(k_layer.dtype).transpose(2, 0, 1, 3).reshape(kv, b * s, d)
-    v_rows = v_new.astype(v_layer.dtype).transpose(2, 0, 1, 3).reshape(kv, b * s, d)
-    kf = kf.at[:, flat].set(k_rows, mode="drop")
-    vf = vf.at[:, flat].set(v_rows, mode="drop")
-    return kf.reshape(kv, p, bs, d), vf.reshape(kv, p, bs, d)
+    flat = jnp.where(valid, page * bs + pos % bs, p * bs).reshape(-1)  # OOB → drop
+
+    def put(pool, new):
+        rows = pool.reshape(n_layers, p * bs, kv, d).at[layer, flat].set(
+            new.reshape(b * s, kv, d).astype(pool.dtype), mode="drop"
+        )
+        return rows.reshape(pool.shape)
+
+    return put(k_pool, k_new), put(v_pool, v_new)
 
 
 def paged_cache_write_token(
-    k_layer: jax.Array,       # (KV, P, bs, D)
-    v_layer: jax.Array,
+    k_pool: jax.Array,        # (L, P, bs, KV, D)
+    v_pool: jax.Array,
+    layer: jax.Array,         # scalar int32
     k_new: jax.Array,         # (B, 1, KV, D)
     v_new: jax.Array,
     block_tables: jax.Array,  # (B, MB)
@@ -248,40 +255,27 @@ def paged_cache_write_token(
     rows absorb garbage harmlessly), paged pages are shared through the
     allocator, so inactive slots MUST NOT write — their row could alias a
     page now owned by another slot."""
-    kv, p, bs, d = k_layer.shape
-    b = positions.shape[0]
-    mb = block_tables.shape[1]
-    blk = positions // bs
-    page = jnp.take_along_axis(
-        block_tables, jnp.clip(blk, 0, mb - 1)[:, None], axis=1
-    )[:, 0]
-    valid = active & (page >= 0) & (blk < mb)
-    flat = jnp.where(valid, page * bs + positions % bs, p * bs)
-    kf = k_layer.reshape(kv, p * bs, d)
-    vf = v_layer.reshape(kv, p * bs, d)
-    kf = kf.at[:, flat].set(k_new[:, 0].transpose(1, 0, 2).astype(k_layer.dtype), mode="drop")
-    vf = vf.at[:, flat].set(v_new[:, 0].transpose(1, 0, 2).astype(v_layer.dtype), mode="drop")
-    return kf.reshape(kv, p, bs, d), vf.reshape(kv, p, bs, d)
+    return paged_cache_write(
+        k_pool, v_pool, layer, k_new, v_new, block_tables, positions,
+        active.astype(jnp.int32),
+    )
 
 
 def paged_gather_kv(
-    k_layer: jax.Array,       # (KV, P, bs, D)
-    v_layer: jax.Array,
+    k_pool: jax.Array,        # (L, P, bs, KV, D)
+    v_pool: jax.Array,
+    layer: jax.Array,         # scalar int32 — the layer read
     block_tables: jax.Array,  # (B, MB)
 ) -> Tuple[jax.Array, jax.Array]:
-    """Assemble each slot's logical KV sequence, (B, MB·bs, KV, D), from the
-    page pool — the pure-jnp realization of what the Pallas paged kernel does
-    with block-table-indirected DMA. Unallocated pages read page 0; callers
-    mask those positions via ``paged_key_positions``."""
-    kv, p, bs, d = k_layer.shape
+    """Assemble each slot's logical KV sequence, (B, MB·bs, KV, D), from
+    ``layer`` of the page pool — the pure-jnp realization of what the Pallas
+    paged kernel does with block-table-indirected DMA. Unallocated pages
+    read page 0; callers mask those positions via ``paged_key_positions``."""
+    _, _, bs, kv, d = k_pool.shape
     b, mb = block_tables.shape
-    idx = jnp.arange(mb * bs, dtype=jnp.int32)
-    page = block_tables[:, idx // bs]                                  # (B, MB·bs)
-    flat = jnp.where(page >= 0, page * bs + idx % bs, 0).reshape(-1)
-    k_ctx = k_layer.reshape(kv, p * bs, d)[:, flat]
-    v_ctx = v_layer.reshape(kv, p * bs, d)[:, flat]
-    k_ctx = k_ctx.reshape(kv, b, mb * bs, d).transpose(1, 2, 0, 3)
-    v_ctx = v_ctx.reshape(kv, b, mb * bs, d).transpose(1, 2, 0, 3)
+    pages = jnp.maximum(block_tables, 0)
+    k_ctx = k_pool[layer, pages].reshape(b, mb * bs, kv, d)
+    v_ctx = v_pool[layer, pages].reshape(b, mb * bs, kv, d)
     return k_ctx, v_ctx
 
 

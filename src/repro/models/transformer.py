@@ -246,6 +246,18 @@ class TransformerLM:
             self.hd, n_slots, max_pages_per_slot, self.dtype,
         )
 
+    def _paged_layer_scan(self, body, h, params: Params, cache):
+        """Run ``body((h, k_pool, v_pool), (layer_params, layer_index))``
+        over the layers with the whole paged pool in the carry: each layer
+        scatters its new K/V into the pool at its own index and reads it
+        back there, so the donated pool is updated in place rather than
+        rebuilt as a stacked scan output. Returns (h, k_pool, v_pool)."""
+        layers = jnp.arange(self.cfg.n_layers, dtype=jnp.int32)
+        (h, k_pool, v_pool), _ = jax.lax.scan(
+            body, (h, cache["k"], cache["v"]), (params["blocks"], layers)
+        )
+        return h, k_pool, v_pool
+
     # ------------------------------------------------------------------ #
     # Serving: prefill                                                    #
     # ------------------------------------------------------------------ #
@@ -348,8 +360,9 @@ class TransformerLM:
         tables = cache["block_tables"][jnp.clip(slot_ids, 0, n_slots - 1)]
         new_lens = starts + chunk_lens
 
-        def body(h, xs):
-            lp, kc, vc = xs
+        def body(carry, xs):
+            h, kp, vp = carry
+            lp, i = xs
             x = apply_norm(h, lp["norm_attn"], cfg.norm_kind, cfg.norm_eps)
             q, k, v = self._qkv_block(x, lp)
             if cfg.m_rope:
@@ -358,9 +371,9 @@ class TransformerLM:
             else:
                 q = apply_rope(q, positions, cfg.rope_theta)
                 k = apply_rope(k, positions, cfg.rope_theta)
-            kc, vc = paged_cache_write(kc, vc, k, v, tables, starts, chunk_lens)
+            kp, vp = paged_cache_write(kp, vp, i, k, v, tables, starts, chunk_lens)
             attn_out = attention_paged(
-                q, kc, vc, tables,
+                q, kp, vp, i, tables,
                 q_positions=positions,
                 valid_lengths=new_lens,
                 causal=True,
@@ -369,11 +382,9 @@ class TransformerLM:
             if cfg.use_bias:
                 attn_out = attn_out + lp["bo"]
             h = self._mlp_block(h + attn_out, lp)
-            return h, (kc, vc)
+            return (h, kp, vp), None
 
-        h, (k_all, v_all) = jax.lax.scan(
-            body, h, (params["blocks"], cache["k"], cache["v"])
-        )
+        h, k_all, v_all = self._paged_layer_scan(body, h, params, cache)
         h = apply_norm(h, params["norm_final"], cfg.norm_kind, cfg.norm_eps)
         h_last = h[jnp.arange(b), jnp.maximum(chunk_lens - 1, 0)]
         logits = unembed(h_last, params["embed"]).astype(jnp.float32)
@@ -481,8 +492,8 @@ class TransformerLM:
         ch_new_lens = chunk_starts + chunk_lens
 
         def body(carry, xs):
-            h_d, h_c = carry
-            lp, kc, vc = xs
+            (h_d, h_c), kp, vp = carry
+            lp, i = xs
             # projections for both sub-batches
             x_d = apply_norm(h_d, lp["norm_attn"], cfg.norm_kind, cfg.norm_eps)
             q_d, k_d, v_d = self._qkv_block(x_d, lp)
@@ -500,18 +511,18 @@ class TransformerLM:
                 k_c = apply_rope(k_c, ch_pos, cfg.rope_theta)
             # all page writes land before either attention reads — the
             # sub-batches own disjoint slots, so write order is irrelevant
-            kc, vc = paged_cache_write(
-                kc, vc, k_c, v_c, ch_tables, chunk_starts, chunk_lens
+            kp, vp = paged_cache_write(
+                kp, vp, i, k_c, v_c, ch_tables, chunk_starts, chunk_lens
             )
-            kc, vc = paged_cache_write_token(
-                kc, vc, k_d, v_d, dec_tables, lengths, dec_active
+            kp, vp = paged_cache_write_token(
+                kp, vp, i, k_d, v_d, dec_tables, lengths, dec_active
             )
             attn_c = attention_paged(
-                q_c, kc, vc, ch_tables,
+                q_c, kp, vp, i, ch_tables,
                 q_positions=ch_pos, valid_lengths=ch_new_lens, causal=True,
             )
             attn_d = attention_paged(
-                q_d, kc, vc, dec_tables,
+                q_d, kp, vp, i, dec_tables,
                 q_positions=dec_pos, valid_lengths=lengths + grow, causal=True,
             )
             attn_d = jnp.einsum("bshk,hkd->bsd", attn_d, lp["wo"])
@@ -521,12 +532,12 @@ class TransformerLM:
                 attn_c = attn_c + lp["bo"]
             h_d = self._mlp_block(h_d + attn_d, lp)
             h_c = self._mlp_block(h_c + attn_c, lp)
-            return (h_d, h_c), (kc, vc)
+            return ((h_d, h_c), kp, vp), None
 
         h_d = embed_tokens(dec_tokens[:, None], params["embed"]).astype(self.dtype)
         h_c = embed_tokens(chunk_tokens, params["embed"]).astype(self.dtype)
-        (h_d, h_c), (k_all, v_all) = jax.lax.scan(
-            body, (h_d, h_c), (params["blocks"], cache["k"], cache["v"])
+        (h_d, h_c), k_all, v_all = self._paged_layer_scan(
+            body, (h_d, h_c), params, cache
         )
         h_d = apply_norm(h_d, params["norm_final"], cfg.norm_kind, cfg.norm_eps)
         h_c = apply_norm(h_c, params["norm_final"], cfg.norm_kind, cfg.norm_eps)
@@ -658,8 +669,9 @@ class TransformerLM:
         else:
             pos_in = positions
 
-        def body(h, xs):
-            lp, kc, vc = xs
+        def body(carry, xs):
+            h, kp, vp = carry
+            lp, i = xs
             x = apply_norm(h, lp["norm_attn"], cfg.norm_kind, cfg.norm_eps)
             q, k, v = self._qkv_block(x, lp)
             if cfg.m_rope:
@@ -668,11 +680,11 @@ class TransformerLM:
             else:
                 q = apply_rope(q, positions, cfg.rope_theta)
                 k = apply_rope(k, positions, cfg.rope_theta)
-            kc, vc = paged_cache_write_token(kc, vc, k, v, tables, lengths, active)
+            kp, vp = paged_cache_write_token(kp, vp, i, k, v, tables, lengths, active)
             # post-write valid counts: active slots gained one token at
             # position ``lengths``; inactive slots' outputs are ignored
             attn_out = attention_paged(
-                q, kc, vc, tables,
+                q, kp, vp, i, tables,
                 q_positions=positions,
                 valid_lengths=lengths + grow,
                 causal=True,
@@ -681,12 +693,10 @@ class TransformerLM:
             if cfg.use_bias:
                 attn_out = attn_out + lp["bo"]
             h = self._mlp_block(h + attn_out, lp)
-            return h, (kc, vc)
+            return (h, kp, vp), None
 
         h = embed_tokens(tokens[:, None], params["embed"]).astype(self.dtype)
-        h, (k_all, v_all) = jax.lax.scan(
-            body, h, (params["blocks"], cache["k"], cache["v"])
-        )
+        h, k_all, v_all = self._paged_layer_scan(body, h, params, cache)
         h = apply_norm(h, params["norm_final"], cfg.norm_kind, cfg.norm_eps)
         logits = unembed(h[:, 0, :], params["embed"]).astype(jnp.float32)
         new_cache = dict(cache)

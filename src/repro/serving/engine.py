@@ -324,7 +324,7 @@ class SlotCheckpoint:
     emitted: int                          # sampler cursor (bound slots)
     pending_token: int                    # next decode round's input token
     kv_length: int                        # valid KV entries in the payload
-    k_pages: Any                          # (L, KV, n_pages, page_size, D)
+    k_pages: Any                          # (L, n_pages, page_size, KV, D)
     v_pages: Any
     n_pages: int
     prefix: List[int]                     # every token generated so far

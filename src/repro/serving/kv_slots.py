@@ -578,12 +578,8 @@ class PagedSlotManager:
 
     def _copy_page(self, src: int, dst: int) -> None:
         """Device copy of one page's K/V content (the COW divergence page)."""
-        self.cache["k"] = self.cache["k"].at[:, :, dst].set(
-            self.cache["k"][:, :, src]
-        )
-        self.cache["v"] = self.cache["v"].at[:, :, dst].set(
-            self.cache["v"][:, :, src]
-        )
+        self.cache["k"] = self.cache["k"].at[:, dst].set(self.cache["k"][:, src])
+        self.cache["v"] = self.cache["v"].at[:, dst].set(self.cache["v"][:, src])
         self.device_writes += 2
         self.cow_copies += 1
         if self.obs is not None:
@@ -705,7 +701,7 @@ class PagedSlotManager:
         """Gather ``slot``'s KV pages out of the pool for migration.
 
         Returns ``(pages, k_payload, v_payload, kv_length, checksum)``
-        where the payloads are ``(L, KV, n_pages, page_size, D)`` device
+        where the payloads are ``(L, n_pages, page_size, KV, D)`` device
         arrays — a plain gather along the pool's page axis, independent of
         *which* page ids the destination pool will assign — and
         ``checksum`` is a CRC over the payload bytes (``page_checksum``),
@@ -717,8 +713,8 @@ class PagedSlotManager:
         if not pages:
             raise RuntimeError(f"slot {slot} holds no pages to export")
         idx = jnp.asarray(pages, jnp.int32)
-        k = jnp.take(self.cache["k"], idx, axis=2)
-        v = jnp.take(self.cache["v"], idx, axis=2)
+        k = jnp.take(self.cache["k"], idx, axis=1)
+        v = jnp.take(self.cache["v"], idx, axis=1)
         length = int(np.asarray(self.cache["length"][slot]))
         return pages, k, v, length, page_checksum(k, v, length)
 
@@ -750,13 +746,13 @@ class PagedSlotManager:
                     f"slot {slot}: KV payload checksum {got:#010x} != "
                     f"exported {checksum:#010x} — migration payload corrupt"
                 )
-        n = int(k_pages.shape[2])
+        n = int(k_pages.shape[1])
         pages = self._alloc(n)
         idx = jnp.asarray(pages, jnp.int32)
-        self.cache["k"] = self.cache["k"].at[:, :, idx].set(
+        self.cache["k"] = self.cache["k"].at[:, idx].set(
             k_pages.astype(self.cache["k"].dtype)
         )
-        self.cache["v"] = self.cache["v"].at[:, :, idx].set(
+        self.cache["v"] = self.cache["v"].at[:, idx].set(
             v_pages.astype(self.cache["v"].dtype)
         )
         self.tables[slot] = pages

@@ -62,13 +62,13 @@ def test_published_cli_refuses_what_it_would_not_serve(monkeypatch, argv):
 def smoke_run():
     """The chip's serve path end to end at smoke size: paged + mixed +
     on-demand + hybrid, slots and pool fitted by memory_analysis."""
-    # the CPU has no published peaks: give it a 20 MB "HBM", which the
+    # the CPU has no published peaks: give it a 14 MB "HBM", which the
     # smoke model's programs fill at 4 slots with a full pool (16 and 8
     # slots do not fit at any pool the fit tries)
     with pytest.MonkeyPatch.context() as mp:
         mp.setitem(
             CHIP_PEAKS, jax.devices()[0].device_kind,
-            ChipPeaks(flops_bf16=1.0, hbm_bw=1.0, hbm_bytes=20e6, ici_bw_per_link=1.0),
+            ChipPeaks(flops_bf16=1.0, hbm_bw=1.0, hbm_bytes=14e6, ici_bw_per_link=1.0),
         )
         return serve_published(get_smoke_config("llama_65b"), "smoke", n_requests=4)
 

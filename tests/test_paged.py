@@ -307,6 +307,99 @@ def test_engine_dense_checkpoint_keeps_straggler_state(model_and_params):
 
 
 # --------------------------------------------------------------------------- #
+# Pool layout (L, P, bs, KV, D): write / write_token / gather vs NumPy        #
+# --------------------------------------------------------------------------- #
+L_, P_, BS_, KV_, D_ = 3, 10, 4, 2, 3
+# slot 0: pages 7, 2; slot 1: page 5 then an unallocated block; slot 2: none
+TABLES = np.array([[7, 2, -1], [5, -1, -1], [-1, -1, -1]], np.int32)
+
+
+def _random_pool(seed):
+    rng = np.random.default_rng(seed)
+    return (
+        rng.standard_normal((L_, P_, BS_, KV_, D_)).astype(np.float32),
+        rng.standard_normal((L_, P_, BS_, KV_, D_)).astype(np.float32),
+    )
+
+
+def _np_write(pool, layer, new, tables, starts, lens):
+    """Reference scatter: token t of row b lands at position starts[b] + t,
+    in page tables[b, pos // bs] at offset pos % bs, unless it lies past
+    lens[b], past the table, or on an unallocated page."""
+    out = pool.copy()
+    mb = tables.shape[1]
+    for b in range(new.shape[0]):
+        for t in range(int(lens[b])):
+            pos = int(starts[b]) + t
+            blk = pos // BS_
+            if blk < mb and tables[b, blk] >= 0:
+                out[layer, tables[b, blk], pos % BS_] = new[b, t]
+    return out
+
+
+@pytest.mark.parametrize("layer", [0, 2])
+def test_paged_cache_write_matches_numpy_scatter(layer):
+    from repro.models.cache import paged_cache_write
+
+    k, v = _random_pool(layer)
+    rng = np.random.default_rng(10 + layer)
+    k_new = rng.standard_normal((3, 5, KV_, D_)).astype(np.float32)
+    v_new = rng.standard_normal((3, 5, KV_, D_)).astype(np.float32)
+    # row 0 crosses from page 7 into page 2 (positions 2..6); row 1 runs off
+    # its only page into an unallocated block (dropped); row 2 owns no page
+    starts = np.array([2, 1, 0], np.int32)
+    lens = np.array([5, 5, 3], np.int32)
+    kw, vw = paged_cache_write(
+        jnp.asarray(k), jnp.asarray(v), jnp.int32(layer), jnp.asarray(k_new),
+        jnp.asarray(v_new), jnp.asarray(TABLES), jnp.asarray(starts),
+        jnp.asarray(lens),
+    )
+    np.testing.assert_array_equal(np.asarray(kw), _np_write(k, layer, k_new, TABLES, starts, lens))
+    np.testing.assert_array_equal(np.asarray(vw), _np_write(v, layer, v_new, TABLES, starts, lens))
+    assert (np.asarray(kw)[layer, 2, :3] == k_new[0, 2:]).all()   # page boundary
+
+
+@pytest.mark.parametrize("layer", [0, 2])
+def test_paged_cache_write_token_matches_numpy_scatter(layer):
+    from repro.models.cache import paged_cache_write_token
+
+    k, v = _random_pool(20 + layer)
+    rng = np.random.default_rng(30 + layer)
+    k_new = rng.standard_normal((3, 1, KV_, D_)).astype(np.float32)
+    v_new = rng.standard_normal((3, 1, KV_, D_)).astype(np.float32)
+    # slot 0 writes the first token of its second page; slot 1 is inactive
+    # (its write must drop); slot 2 is active but owns no page (drops too)
+    positions = np.array([BS_, 2, 0], np.int32)
+    active = np.array([True, False, True])
+    kw, vw = paged_cache_write_token(
+        jnp.asarray(k), jnp.asarray(v), jnp.int32(layer), jnp.asarray(k_new),
+        jnp.asarray(v_new), jnp.asarray(TABLES), jnp.asarray(positions),
+        jnp.asarray(active),
+    )
+    lens = active.astype(np.int32)
+    np.testing.assert_array_equal(np.asarray(kw), _np_write(k, layer, k_new, TABLES, positions, lens))
+    np.testing.assert_array_equal(np.asarray(vw), _np_write(v, layer, v_new, TABLES, positions, lens))
+    changed = np.argwhere((np.asarray(kw) != k).any(axis=(3, 4)))
+    assert changed.tolist() == [[layer, 2, 0]]     # one (KV, D) row, nothing else
+
+
+@pytest.mark.parametrize("layer", [0, 2])
+def test_paged_gather_kv_matches_numpy_gather(layer):
+    from repro.models.cache import paged_gather_kv
+
+    k, v = _random_pool(40 + layer)
+    kg, vg = paged_gather_kv(
+        jnp.asarray(k), jnp.asarray(v), jnp.int32(layer), jnp.asarray(TABLES)
+    )
+    pages = np.maximum(TABLES, 0)                    # unallocated → page 0
+    shape = (TABLES.shape[0], TABLES.shape[1] * BS_, KV_, D_)
+    np.testing.assert_array_equal(np.asarray(kg), k[layer][pages].reshape(shape))
+    np.testing.assert_array_equal(np.asarray(vg), v[layer][pages].reshape(shape))
+    # token 5 of slot 0 is offset 1 of its second page
+    np.testing.assert_array_equal(np.asarray(kg)[0, 5], k[layer, 2, 1])
+
+
+# --------------------------------------------------------------------------- #
 # Dense-path regressions riding along                                         #
 # --------------------------------------------------------------------------- #
 def test_bucket_overflow_raises():

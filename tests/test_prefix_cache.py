@@ -8,6 +8,7 @@ with refcount invariants at every step, migration of shared pages
 (checksums preserved, never double-freed), cache-aware pricing through
 iteration/offline/hetero, and the shared-prefix workload generator."""
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -51,17 +52,31 @@ def model_and_params():
 class _StubModel:
     """Just enough model for a PagedSlotManager: a tiny paged cache."""
 
+    def __init__(self, n_layers=1, kv_heads=1):
+        self.n_layers, self.kv_heads = n_layers, kv_heads
+
     def paged_cache_init(self, num_pages, page_size, n_slots, mb):
         from repro.models.cache import paged_cache_init
 
-        return paged_cache_init(1, num_pages, page_size, 1, 4, n_slots, mb)
+        return paged_cache_init(
+            self.n_layers, num_pages, page_size, self.kv_heads, 4, n_slots, mb
+        )
 
 
-def _mgr(n_slots=4, max_len=64, page_size=4, num_pages=32, prefix_cache=True):
+def _mgr(n_slots=4, max_len=64, page_size=4, num_pages=32, prefix_cache=True,
+         model=None):
     return PagedSlotManager(
-        _StubModel(), n_slots, max_len, page_size, num_pages,
+        model or _StubModel(), n_slots, max_len, page_size, num_pages,
         prefix_cache=prefix_cache,
     )
+
+
+def _fill_random(mgr, seed):
+    """Distinct random K/V in every page, layer and head of the pool."""
+    rng = np.random.default_rng(seed)
+    for name in ("k", "v"):
+        a = mgr.cache[name]
+        mgr.cache[name] = jnp.asarray(rng.standard_normal(a.shape), a.dtype)
 
 
 def _check_all(mgr):
@@ -281,6 +296,44 @@ def test_export_import_shared_pages_preserves_checksum():
     src.prefix_index.clear()
     assert src.allocator.num_used == 0
     _check_all(dst)
+
+
+def test_export_import_round_trips_kv_bit_exact():
+    """Migration under the (L, P, bs, KV, D) pool: the destination's new
+    pages hold exactly the source pages' K/V in every layer and head, in
+    the slot's page order, and no other destination page changes."""
+    stub = _StubModel(n_layers=3, kv_heads=2)
+    src = _mgr(page_size=4, num_pages=16, prefix_cache=False, model=stub)
+    dst = _mgr(page_size=4, num_pages=16, prefix_cache=False, model=stub)
+    _fill_random(src, 1)
+    _fill_random(dst, 2)
+    dst.reserve(1, 6)                          # dst's new pages are not src's
+    src.reserve(2, 10)                         # 3 pages
+    src.cache["length"] = src.cache["length"].at[2].set(10)
+    before = {n: np.asarray(dst.cache[n]) for n in ("k", "v")}
+    pages, k, v, length, crc = src.export_pages(2)
+    new = dst.import_pages(0, k, v, length, checksum=crc)
+    assert length == 10 and len(new) == len(pages) == 3
+    assert set(new).isdisjoint(dst.tables[1])
+    for n in ("k", "v"):
+        got, want = np.asarray(dst.cache[n]), np.asarray(src.cache[n])
+        np.testing.assert_array_equal(got[:, new], want[:, pages])
+        others = [p for p in range(16) if p not in new]
+        np.testing.assert_array_equal(got[:, others], before[n][:, others])
+    assert int(dst.cache["length"][0]) == 10
+    _check_all(dst)
+
+
+def test_copy_page_copies_one_page_in_every_layer_and_head():
+    mgr = _mgr(page_size=4, num_pages=8, model=_StubModel(n_layers=3, kv_heads=2))
+    _fill_random(mgr, 3)
+    before = {n: np.asarray(mgr.cache[n]) for n in ("k", "v")}
+    mgr._copy_page(5, 2)
+    for n in ("k", "v"):
+        want = before[n].copy()
+        want[:, 2] = before[n][:, 5]
+        np.testing.assert_array_equal(np.asarray(mgr.cache[n]), want)
+    assert mgr.cow_copies == 1
 
 
 def test_import_bit_flip_rejected_pool_untouched():

@@ -2,15 +2,19 @@
 
 The TPU compiler refuses what interpret mode accepts: block shapes that
 break the tiling rule, programs that do not fit HBM. These tests compile the
-serving path's jitted programs at LLaMA-65B widths (depth cut to 2 layers)
-and every Pallas kernel under ``repro.kernels`` for one chip of a described
-``v5e:2x2`` topology. Nothing runs; shapes come from ``jax.eval_shape``.
+serving path's jitted programs at LLaMA-65B and Granite-3-8B widths (depth
+cut to 2 layers) and every Pallas kernel under ``repro.kernels`` for one
+chip of a described ``v5e:2x2`` topology. Nothing runs; shapes come from
+``jax.eval_shape``. The serving programs must update the donated KV pool in
+place: no copy of the whole pool, and temporaries smaller than the pool.
 
 The topology is described inside a module-scoped fixture, never at import:
 only one process may load the TPU library, and the test workers all import
 this file."""
 import dataclasses
+import math
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -29,6 +33,10 @@ from repro.serving.engine import serving_jits
 from repro.serving.sampler import greedy
 
 N_SLOTS, MAX_LEN, PAGE, CHUNK, ROWS = 16, 1024, 16, 32, 16
+# Granite at the benchmark cell's 32 slots, over 3/4 of a full pool
+GRANITE_SLOTS, GRANITE_PAGES = 32, 1536
+PROGRAMS = ("fused_decode", "mixed_round", "chunk_round")
+WIDTHS = ("llama_2l", "granite_2l")
 
 
 @pytest.fixture(scope="module")
@@ -61,19 +69,31 @@ def _hbm_total(compiled) -> int:
     )
 
 
-@pytest.fixture(scope="module")
-def llama_2l(one_chip):
-    """LLaMA-65B at published widths, 2 of 80 layers, with the paged pool
-    at full capacity for N_SLOTS × MAX_LEN — all as abstract shapes."""
-    cfg = dataclasses.replace(get_config("llama_65b"), n_layers=2)
+def _paged_model(sharding, arch, n_slots, num_pages):
+    """``arch`` at its widths, 2 layers, and a paged pool of ``num_pages``
+    for ``n_slots`` × MAX_LEN — all as abstract shapes."""
+    cfg = dataclasses.replace(get_config(arch), n_layers=2)
     model = TransformerLM(cfg)
     defs = model.param_defs()
     params = jax.eval_shape(lambda k: init_params(k, defs), jax.random.key(0))
     mb = MAX_LEN // PAGE
     cache = jax.eval_shape(
-        lambda: model.paged_cache_init(N_SLOTS * mb, PAGE, N_SLOTS, mb)
+        lambda: model.paged_cache_init(num_pages, PAGE, n_slots, mb)
     )
-    return model, _on(one_chip, params), _on(one_chip, cache)
+    return model, _on(sharding, params), _on(sharding, cache)
+
+
+@pytest.fixture(scope="module")
+def llama_2l(one_chip):
+    """LLaMA-65B at published widths (64 KV heads, no grouping), 2 of 80
+    layers, with the pool at full capacity for N_SLOTS × MAX_LEN."""
+    return _paged_model(one_chip, "llama_65b", N_SLOTS, N_SLOTS * MAX_LEN // PAGE)
+
+
+@pytest.fixture(scope="module")
+def granite_2l(one_chip):
+    """Granite-3-8B at published widths, 2 of 40 layers, 32 slots."""
+    return _paged_model(one_chip, "granite_3_8b", GRANITE_SLOTS, GRANITE_PAGES)
 
 
 def _i32(sharding, *shape):
@@ -84,27 +104,98 @@ def _bool(sharding, *shape):
     return jax.ShapeDtypeStruct(shape, jnp.bool_, sharding=sharding)
 
 
-def test_fused_decode_compiles_at_llama65b_widths(one_chip, llama_2l):
-    model, params, cache = llama_2l
-    _, _, fused = serving_jits(model, greedy, None)
-    s = one_chip
-    compiled = fused.lower(
-        8, params, _i32(s, N_SLOTS), cache, _bool(s, N_SLOTS),
-        _i32(s, N_SLOTS), _i32(s, N_SLOTS), _i32(s, N_SLOTS), None,
-    ).compile()
-    assert _hbm_total(compiled) < chip_peaks("TPU v5 lite").hbm_bytes
+def _lower(program, s, model, params, cache, horizon):
+    """``program`` of the engine's serving jits against abstract shapes: the
+    slot count is the pool's, and the mixed and chunk rounds take ROWS rows
+    of CHUNK tokens."""
+    chunk, mixed, fused = serving_jits(model, greedy, None)
+    j, r = cache["block_tables"].shape[0], ROWS
+    if program == "fused_decode":
+        return fused.lower(
+            horizon, params, _i32(s, j), cache, _bool(s, j),
+            _i32(s, j), _i32(s, j), _i32(s, j), None,
+        )
+    if program == "mixed_round":
+        return mixed.lower(
+            params, _i32(s, j), cache, _i32(s, r, CHUNK), _i32(s, r),
+            _i32(s, r), _i32(s, r), _bool(s, j), _i32(s, j + r),
+            _i32(s, j + r), _bool(s, j + r), None,
+        )
+    return chunk.lower(params, _i32(s, r, CHUNK), cache, _i32(s, r), _i32(s, r), _i32(s, r))
 
 
-def test_mixed_step_compiles_at_llama65b_widths(one_chip, llama_2l):
-    model, params, cache = llama_2l
-    _, mixed, _ = serving_jits(model, greedy, None)
-    s, j, r = one_chip, N_SLOTS, ROWS
-    compiled = mixed.lower(
-        params, _i32(s, j), cache, _i32(s, r, CHUNK), _i32(s, r), _i32(s, r),
-        _i32(s, r), _bool(s, j), _i32(s, j + r), _i32(s, j + r),
-        _bool(s, j + r), None,
-    ).compile()
-    assert _hbm_total(compiled) < chip_peaks("TPU v5 lite").hbm_bytes
+@pytest.fixture(scope="module")
+def compiled(request, one_chip):
+    """Compile each (width, program, horizon) once for the whole module."""
+    memo = {}
+
+    def get(width, program, horizon=8):
+        key = (width, program, horizon if program == "fused_decode" else None)
+        if key not in memo:
+            model, params, cache = request.getfixturevalue(width)
+            memo[key] = _lower(
+                program, one_chip, model, params, cache, horizon
+            ).compile()
+        return memo[key]
+
+    return get
+
+
+_INSTR = re.compile(
+    r"%(?P<name>[\w.\-]+) = \w+\[(?P<dims>[\d,]*)\]\{[^}]*\} (?P<op>[\w\-]+)\("
+)
+
+
+def _pool_copies(text, pool_shape):
+    """Names of the copies (``copy``, ``copy-done``, or a fusion the compiler
+    named for a copy) whose result holds as many elements as a whole K or V
+    pool, in any layout: a pool that is rebuilt rather than updated."""
+    n = math.prod(pool_shape)
+    out = []
+    for m in _INSTR.finditer(text):
+        is_copy = m["op"] in ("copy", "copy-done") or (
+            m["op"] == "fusion" and "copy" in m["name"]
+        )
+        dims = [int(d) for d in m["dims"].split(",") if d]
+        if is_copy and math.prod(dims) == n:
+            out.append(m["name"])
+    return out
+
+
+def test_fused_decode_compiles_at_llama65b_widths(llama_2l, compiled):
+    c = compiled("llama_2l", "fused_decode")
+    assert _hbm_total(c) < chip_peaks("TPU v5 lite").hbm_bytes
+    assert _pool_copies(c.as_text(), llama_2l[2]["k"].shape) == []
+
+
+def test_mixed_step_compiles_at_llama65b_widths(llama_2l, compiled):
+    c = compiled("llama_2l", "mixed_round")
+    assert _hbm_total(c) < chip_peaks("TPU v5 lite").hbm_bytes
+    assert _pool_copies(c.as_text(), llama_2l[2]["k"].shape) == []
+
+
+@pytest.mark.parametrize("program", PROGRAMS)
+@pytest.mark.parametrize("width", WIDTHS)
+def test_serving_program_never_copies_the_pool(request, compiled, width, program):
+    """The layer scans carry the pool and scatter each layer's K/V into it:
+    no program holds a copy of the whole pool (the fused decode at the
+    horizon the pool fit compiles, 8)."""
+    cache = request.getfixturevalue(width)[2]
+    text = compiled(width, program).as_text()
+    assert _pool_copies(text, cache["k"].shape) == []
+
+
+@pytest.mark.parametrize("program", PROGRAMS)
+@pytest.mark.parametrize("width", WIDTHS)
+def test_serving_program_temporaries_below_pool(request, compiled, width, program):
+    """Temporaries stay below the K+V pool's bytes. The fused decode is
+    taken at one round per dispatch, the benchmark cell's horizon: at
+    horizon 8 and 64 KV heads, the attention's float32 copy of every slot's
+    gathered block table (the dense gather) outgrows the pool by itself."""
+    cache = request.getfixturevalue(width)[2]
+    pool_bytes = 2 * cache["k"].size * cache["k"].dtype.itemsize
+    c = compiled(width, program, horizon=1)
+    assert c.memory_analysis().temp_size_in_bytes < pool_bytes
 
 
 def _kernel_text(fn, *args) -> str:

@@ -233,7 +233,8 @@ class StageRecord:
     # Engine stages only: wall seconds of each phase of the serve step that
     # ran this stage (``schedule``, ``kv``, ``prepare``, ``launch``,
     # ``wait``, ``commit``; launch + wait is the stage's measured time), and
-    # the eager device writes the KV pool dispatched in that step.
+    # the device writes the KV pool made in that step (block-table uploads,
+    # length writes, page copies).
     host: Dict[str, float] = field(default_factory=dict)
     pool_writes: int = 0
 

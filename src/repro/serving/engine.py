@@ -1719,9 +1719,10 @@ class Engine:
         A step that runs a stage stamps the stage's record with the wall
         seconds of its phases (``StageRecord.host``: ``schedule``, ``kv``,
         ``prepare``, ``launch``, ``wait``, ``commit``, in that order; a retry
-        after an eviction adds to them) and the KV pool's eager device
-        writes in the step (``StageRecord.pool_writes``). Each phase is also
-        a ``jax.profiler.TraceAnnotation`` named ``engine.<phase>``.
+        after an eviction adds to them) and the KV pool's device writes in
+        the step (``StageRecord.pool_writes``: the block-table upload at the
+        end of ``kv``, eager length writes and page copies). Each phase is
+        also a ``jax.profiler.TraceAnnotation`` named ``engine.<phase>``.
         """
         trace = self._sv.trace
         n_stages = len(trace.stages)
@@ -1887,6 +1888,7 @@ class Engine:
                     ]
                     if not self.slots.active_slots:
                         continue   # every decode lane was evicted — re-plan
+                self.slots.flush_block_tables()
                 ph.mark("prepare")
                 (
                     dt, fin_decode, decode_tok, chunk_tok, fin_chunks,
@@ -1931,6 +1933,7 @@ class Engine:
                     self._commit_pairs(pairs)
                     sv.bin_index += 1
                     self._start_chunked_batch(pairs, sv.bin_index, t)
+                self.slots.flush_block_tables()
                 ph.mark("prepare")
                 dt, tok, finished, busy, busy_partial = self._run_chunk_round()
                 if active:
@@ -2008,6 +2011,8 @@ class Engine:
                     )
                     if not self.slots.active_slots:
                         continue   # every decode lane was evicted — re-plan
+                if paged:
+                    self.slots.flush_block_tables()
                 ph.mark("prepare")
                 dt, finished, tokens = self._run_decode_stage(k)
                 # the stage right after a preempting prefill carries the

@@ -469,8 +469,14 @@ class PagedSlotManager:
     grows the slot's table page-by-page as decode crosses page boundaries.
     When growth finds the pool exhausted the *engine* preempts a
     lowest-priority slot (``free_pages_of`` + re-queue) — the manager only
-    does page bookkeeping. Block table rows are mirrored to the device cache
-    on reserve/grow/release."""
+    does page bookkeeping.
+
+    The host owns the block table: reserve/grow/release/import write rows
+    of ``host_tables`` (``(n_slots, max_pages_per_slot)`` int32, −1 where
+    unallocated) and mark it dirty, and ``flush_block_tables`` uploads the
+    whole array as the cache's ``block_tables`` leaf in one transfer. The
+    engine flushes once per step before it prepares a launch; ``cache``
+    flushes on read, so no reader of the device table sees it stale."""
 
     def __init__(
         self,
@@ -490,9 +496,13 @@ class PagedSlotManager:
             num_pages if num_pages is not None
             else n_slots * self.max_pages_per_slot
         )
-        self.cache = model.paged_cache_init(
+        self._cache = model.paged_cache_init(
             self.num_pages, page_size, n_slots, self.max_pages_per_slot
         )
+        self.host_tables = np.full(
+            (n_slots, self.max_pages_per_slot), -1, np.int32
+        )
+        self.tables_dirty = False
         self.allocator = BlockAllocator(self.num_pages, page_size)
         self.prefix_index: Optional[PrefixCacheIndex] = (
             PrefixCacheIndex(self.allocator, page_size) if prefix_cache else None
@@ -503,8 +513,8 @@ class PagedSlotManager:
         self.peak_pages = 0
         self.shared_pages_peak = 0
         self.cow_copies = 0
-        # eager device writes dispatched to the pool (block-table rows,
-        # lengths, page copies), one per scatter: the engine stamps each
+        # device writes to the pool (block-table uploads, lengths, page
+        # copies), one per transfer or scatter: the engine stamps each
         # step's count on its StageRecord.pool_writes
         self.device_writes = 0
         # observability hooks, set by the owning engine when a serve opts
@@ -534,16 +544,39 @@ class PagedSlotManager:
             [r is not None for r in self.request_of], dtype=jnp.bool_
         )
 
-    # -- page ownership ------------------------------------------------ #
-    def _mirror_row(self, slot: int) -> None:
-        """Push ``slot``'s host block-table row to the device cache."""
-        row = np.full((self.max_pages_per_slot,), -1, np.int32)
-        pages = self.tables[slot]
-        row[: len(pages)] = pages
-        self.cache["block_tables"] = (
-            self.cache["block_tables"].at[slot].set(jnp.asarray(row))
+    # -- the block table: host-owned, uploaded on flush ----------------- #
+    @property
+    def cache(self) -> Tree:
+        """The device pool, its block table flushed first."""
+        self.flush_block_tables()
+        return self._cache
+
+    @cache.setter
+    def cache(self, value: Tree) -> None:
+        self._cache = value
+
+    def flush_block_tables(self) -> None:
+        """Upload ``host_tables`` as the cache's ``block_tables`` in one
+        transfer, if a row changed since the last upload. The upload is a
+        copy: the host array keeps taking edits in place."""
+        if not self.tables_dirty:
+            return
+        # placed as the leaf it replaces, committed or not: a change of
+        # either would compile the serving programs again
+        old = self._cache["block_tables"]
+        self._cache["block_tables"] = jax.device_put(
+            self.host_tables.copy(), old.sharding if old.committed else None
         )
+        self.tables_dirty = False
         self.device_writes += 1
+
+    def _mirror_row(self, slot: int) -> None:
+        """Write ``slot``'s page list into its host block-table row."""
+        pages = self.tables[slot]
+        row = self.host_tables[slot]
+        row[: len(pages)] = pages
+        row[len(pages):] = -1
+        self.tables_dirty = True
 
     def _alloc(self, n_pages: int) -> List[int]:
         """Allocate fresh pages, evicting index-only cached pages on demand
@@ -556,8 +589,8 @@ class PagedSlotManager:
         return pages
 
     def reserve(self, slot: int, n_tokens: int) -> None:
-        """Give ``slot`` pages covering ``n_tokens`` and mirror its block
-        table row to the device."""
+        """Give ``slot`` pages covering ``n_tokens`` and write its block
+        table row."""
         if self.tables[slot]:
             raise RuntimeError(f"slot {slot} already holds pages")
         n_tokens = min(n_tokens, self.max_len)
@@ -578,8 +611,9 @@ class PagedSlotManager:
 
     def _copy_page(self, src: int, dst: int) -> None:
         """Device copy of one page's K/V content (the COW divergence page)."""
-        self.cache["k"] = self.cache["k"].at[:, dst].set(self.cache["k"][:, src])
-        self.cache["v"] = self.cache["v"].at[:, dst].set(self.cache["v"][:, src])
+        c = self._cache
+        c["k"] = c["k"].at[:, dst].set(c["k"][:, src])
+        c["v"] = c["v"].at[:, dst].set(c["v"][:, src])
         self.device_writes += 2
         self.cow_copies += 1
         if self.obs is not None:
@@ -631,7 +665,7 @@ class PagedSlotManager:
             self.shared_pages_peak, self.allocator.num_shared()
         )
         self._mirror_row(slot)
-        self.cache["length"] = self.cache["length"].at[slot].set(int(cached))
+        self._cache["length"] = self._cache["length"].at[slot].set(int(cached))
         self.device_writes += 1
         return int(cached)
 
@@ -687,12 +721,14 @@ class PagedSlotManager:
         return req
 
     def free_pages_of(self, slot: int) -> None:
+        """Return ``slot``'s pages, clear its block-table row (uploaded by
+        the next flush) and zero its device KV length (one eager write)."""
         if self.tables[slot]:
             self.allocator.free(self.tables[slot])
             self.tables[slot] = []
-        self.cache["block_tables"] = self.cache["block_tables"].at[slot].set(-1)
-        self.cache["length"] = self.cache["length"].at[slot].set(0)
-        self.device_writes += 2
+        self._mirror_row(slot)
+        self._cache["length"] = self._cache["length"].at[slot].set(0)
+        self.device_writes += 1
 
     # -- page-copy migration (live cross-engine slot transfer) ---------- #
     def export_pages(
@@ -713,9 +749,10 @@ class PagedSlotManager:
         if not pages:
             raise RuntimeError(f"slot {slot} holds no pages to export")
         idx = jnp.asarray(pages, jnp.int32)
-        k = jnp.take(self.cache["k"], idx, axis=1)
-        v = jnp.take(self.cache["v"], idx, axis=1)
-        length = int(np.asarray(self.cache["length"][slot]))
+        cache = self.cache
+        k = jnp.take(cache["k"], idx, axis=1)
+        v = jnp.take(cache["v"], idx, axis=1)
+        length = int(np.asarray(cache["length"][slot]))
         return pages, k, v, length, page_checksum(k, v, length)
 
     def import_pages(
@@ -749,27 +786,26 @@ class PagedSlotManager:
         n = int(k_pages.shape[1])
         pages = self._alloc(n)
         idx = jnp.asarray(pages, jnp.int32)
-        self.cache["k"] = self.cache["k"].at[:, idx].set(
-            k_pages.astype(self.cache["k"].dtype)
-        )
-        self.cache["v"] = self.cache["v"].at[:, idx].set(
-            v_pages.astype(self.cache["v"].dtype)
-        )
+        c = self._cache
+        c["k"] = c["k"].at[:, idx].set(k_pages.astype(c["k"].dtype))
+        c["v"] = c["v"].at[:, idx].set(v_pages.astype(c["v"].dtype))
         self.tables[slot] = pages
         self.peak_pages = max(self.peak_pages, self.allocator.num_used)
         self._mirror_row(slot)
-        self.cache["length"] = self.cache["length"].at[slot].set(int(kv_length))
+        c["length"] = c["length"].at[slot].set(int(kv_length))
         self.device_writes += 3
         return pages
 
     def check_block_table_mirror(self) -> None:
         """The host ``tables`` and the device ``block_tables`` must describe
-        the same page ownership row for row, and a slot owning no pages must
-        hold no KV length — a divergence means a reserve/grow/release path
-        skipped its mirror write (``EngineConfig.debug_invariants`` asserts
+        the same page ownership row for row once flushed, and a slot owning
+        no pages must hold no KV length — a divergence means a path edited
+        ``tables`` without its host row, or the device table was written
+        behind the host's back (``EngineConfig.debug_invariants`` asserts
         this at stage boundaries)."""
-        bt = np.asarray(self.cache["block_tables"])
-        lengths = np.asarray(self.cache["length"])
+        cache = self.cache
+        bt = np.asarray(cache["block_tables"])
+        lengths = np.asarray(cache["length"])
         for slot, pages in enumerate(self.tables):
             row = np.full((self.max_pages_per_slot,), -1, np.int32)
             row[: len(pages)] = pages
@@ -811,8 +847,11 @@ class PagedSlotManager:
         Refcounts are rebuilt from block-table multiplicity (a page shared
         by k rows appears k times); the prefix index's holds are not part
         of the device record, so the index restarts cold."""
-        bt = np.asarray(self.cache["block_tables"])
-        self.tables = [[int(p) for p in row if p >= 0] for row in bt]
+        self.host_tables = np.array(self._cache["block_tables"], np.int32)
+        self.tables_dirty = False
+        self.tables = [
+            [int(p) for p in row if p >= 0] for row in self.host_tables
+        ]
         self.allocator.reset([p for row in self.tables for p in row])
         if self.prefix_index is not None:
             self.prefix_index.invalidate()
@@ -826,7 +865,7 @@ class PagedSlotManager:
         )
 
     def kv_bytes_capacity(self) -> int:
-        return self.cache["k"].nbytes + self.cache["v"].nbytes
+        return self._cache["k"].nbytes + self._cache["v"].nbytes
 
     def peak_kv_bytes(self) -> int:
         """High-water mark of slot-owned KV bytes over the run."""

@@ -1,10 +1,14 @@
 """Wall-clock phases of a serve step and the KV pool's device-write counter:
 every stage record carries all six phases with launch + wait equal to the
-stage's measured time, the pool counts one write per page growth and two
-per release, the serving programs compile under their own names, and the
+stage's measured time; the host owns the block table, so page growth and
+release write nothing to the device until a flush, which uploads every
+changed row in one write (a release also writes its length once); no
+serving program launches on a stale table, and the served tokens match the
+dense layout's; the serving programs compile under their own names, and the
 always-on phases fire no observability callback."""
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
 from repro.configs.base import ArchConfig
@@ -91,14 +95,29 @@ def test_every_stage_carries_six_phases(model_and_params, layout):
 
 
 def test_step_pool_writes_add_up_to_the_pool_counter(model_and_params):
-    eng = _engine(*model_and_params, **LAYOUTS["paged-mixed"])
-    before = eng.slots.device_writes
-    trace = _serve(eng, LagrangianPolicy())
-    assert sum(s.pool_writes for s in trace.stages) == (
-        eng.slots.device_writes - before
+    # the debug invariant check reads the device table at the end of every
+    # step, which flushes a release there instead of at the next step's kv
+    # phase: count what a serve without it writes
+    eng = _engine(
+        *model_and_params, **LAYOUTS["paged-mixed"], debug_invariants=False
     )
-    # every request reserved pages and was released (2 writes) in some step
-    assert sum(s.pool_writes for s in trace.stages) >= 3 * SPEC.n_requests
+    pool = eng.slots
+    frees = []
+    free_pages_of = pool.free_pages_of
+
+    def counting_free(slot):
+        frees.append(slot)
+        free_pages_of(slot)
+
+    pool.free_pages_of = counting_free
+    before = pool.device_writes
+    trace = _serve(eng, LagrangianPolicy())
+    total = sum(s.pool_writes for s in trace.stages)
+    assert total == pool.device_writes - before
+    # every request was released; each release writes its length once, and
+    # a step uploads the block table at most once
+    assert len(frees) >= SPEC.n_requests
+    assert len(frees) < total <= len(trace.stages) + len(frees)
     # a pure decode round that crosses no page boundary and finishes no
     # request writes nothing to the pool
     assert any(
@@ -106,18 +125,98 @@ def test_step_pool_writes_add_up_to_the_pool_counter(model_and_params):
     )
 
 
+def _device_rows(pool):
+    """The device block table as it stands, without flushing."""
+    return np.array(pool._cache["block_tables"])
+
+
 def test_page_growth_writes_one_and_release_writes_two(model_and_params):
+    """Growth and release edit host rows only; one flush uploads them all
+    as one write, a clean flush writes nothing, and a release's one eager
+    write is its length. A growth then costs one write and a release two."""
     model, _ = model_and_params
     pool = PagedSlotManager(model, n_slots=2, max_len=64, page_size=16)
     pool.reserve(0, 16)
     pool.bind(0, Request(rid=1, n_prefill=16, n_decode=4))
+    pool.flush_block_tables()
     w = pool.device_writes
-    assert pool.ensure_tokens(0, 16) == 0          # covered: no write
+    pool.flush_block_tables()                      # nothing dirty: no write
     assert pool.device_writes == w
+    assert pool.ensure_tokens(0, 16) == 0          # covered: nothing changes
+    assert not pool.tables_dirty
+    stale = _device_rows(pool)
     assert pool.ensure_tokens(0, 17) == 1          # one more page
-    assert pool.device_writes == w + 1
-    pool.release(0)
+    assert pool.ensure_tokens(0, 40) == 1          # and another
+    pool.reserve(1, 20)
+    assert pool.device_writes == w and pool.tables_dirty
+    np.testing.assert_array_equal(_device_rows(pool), stale)
+    pool.flush_block_tables()                      # three row changes
+    assert pool.device_writes == w + 1 and not pool.tables_dirty
+    np.testing.assert_array_equal(_device_rows(pool), pool.host_tables)
+    for slot, pages in enumerate(pool.tables):
+        assert _device_rows(pool)[slot, : len(pages)].tolist() == pages
+    # the upload is a copy: editing the host array leaves the device alone
+    pool.host_tables[1, 0] += 1
+    assert _device_rows(pool)[1, 0] == pool.tables[1][0]
+    pool.host_tables[1, 0] -= 1
+    pool.release(0)                                # its length: one write
+    assert pool.device_writes == w + 2 and pool.tables_dirty
+    assert (_device_rows(pool)[0] >= 0).sum() == 3
+    assert int(pool._cache["length"][0]) == 0
+    pool.flush_block_tables()
     assert pool.device_writes == w + 3
+    assert (_device_rows(pool)[0] == -1).all()
+
+
+@pytest.fixture(scope="module")
+def dense_tokens(model_and_params):
+    eng = _engine(*model_and_params, **LAYOUTS["dense"])
+    _serve(eng, PrefillFirstPolicy())
+    return eng.generated
+
+
+@pytest.mark.parametrize("layout", ["paged-mixed", "paged-alternating"])
+def test_no_program_launches_on_a_stale_block_table(
+    model_and_params, dense_tokens, layout
+):
+    """At every launch of a serving program the host table is clean and the
+    table the program gets equals the host rows; the upload happens in the
+    step's kv phase (or at the end-of-step debug check), never at launch;
+    and the served tokens are the dense layout's."""
+    eng = _engine(*model_and_params, **LAYOUTS[layout])
+    pool = eng.slots
+    launches = []
+
+    def spy(name, program, cache_arg):
+        def launch(*args):
+            assert not pool.tables_dirty, name
+            np.testing.assert_array_equal(
+                np.array(args[cache_arg]["block_tables"]), pool.host_tables
+            )
+            launches.append(name)
+            return program(*args)
+        return launch
+
+    eng._chunk_jit = spy("chunk_round", eng._chunk_jit, 2)
+    eng._mixed_jit = spy("mixed_round", eng._mixed_jit, 2)
+    eng._fused_jit = spy("fused_decode", eng._fused_jit, 3)
+    uploads = []
+    flush = pool.flush_block_tables
+
+    def spied_flush():
+        if pool.tables_dirty:
+            uploads.append(eng._phases._name)
+        flush()
+
+    pool.flush_block_tables = spied_flush
+    _serve(eng, PrefillFirstPolicy())
+    assert "fused_decode" in launches
+    assert ("mixed_round" if layout == "paged-mixed" else "chunk_round") in (
+        launches
+    )
+    assert "kv" in uploads
+    assert set(uploads) <= {"kv", "commit"}, uploads
+    assert eng.generated == dense_tokens
 
 
 def test_serving_programs_lower_under_their_names(model_and_params):

@@ -89,6 +89,46 @@ def test_paged_slot_manager_reserve_release(model_and_params):
     assert int(mgr.cache["length"][0]) == 0
 
 
+@pytest.mark.parametrize("carrier", ["export_import", "state_dict"])
+def test_grown_row_is_carried_without_an_explicit_flush(
+    model_and_params, carrier
+):
+    """Page growth only edits the host row; migration and checkpoint read
+    the device table through the flushing ``cache``, so a row grown just
+    before them arrives whole on the other side."""
+    model, params = model_and_params
+    kw = dict(page_size=16, prefill_chunk=16, num_pages=16)
+    eng = _engine(model, params, "paged", **kw)
+    req = Request(rid=0, n_prefill=40, n_decode=20)
+    clients = build_clients(4, [req], None)
+    eng._start_chunked_batch([(clients[0], req)], 0, 0.0)
+    eng._run_chunk_round()                        # flushed at launch
+    assert eng.slots.ensure_tokens(0, 60) == 1    # 3 → 4 pages, host only
+    assert eng.slots.tables_dirty
+    grown = list(eng.slots.tables[0])
+    assert len(grown) == 4
+    eng2 = _engine(model, params, "paged", **kw)
+    if carrier == "export_import":
+        pages, k, v, length, crc = eng.slots.export_pages(0)
+        assert pages == grown and k.shape[1] == 4
+        new = eng2.slots.import_pages(1, k, v, length, checksum=crc)
+        got = np.asarray(eng2.slots.cache["block_tables"][1])
+        assert got[:4].tolist() == new and (got[4:] == -1).all()
+    else:
+        eng2.load_state_dict(eng.state_dict(), {0: req})
+        assert eng2.slots.tables[0] == grown
+        assert not eng2.slots.tables_dirty
+        np.testing.assert_array_equal(
+            eng2.slots.host_tables, eng.slots.host_tables
+        )
+    assert not eng.slots.tables_dirty
+    np.testing.assert_array_equal(
+        np.asarray(eng.slots.cache["block_tables"])[0, :4], grown
+    )
+    eng.slots.check_block_table_mirror()
+    eng2.slots.check_block_table_mirror()
+
+
 # --------------------------------------------------------------------------- #
 # Model-level parity: chunked paged prefill + paged decode == dense           #
 # --------------------------------------------------------------------------- #
